@@ -4,10 +4,14 @@ deliverable. Prints ``name,value,derived`` CSV rows.
   PYTHONPATH=src python -m benchmarks.run [--full] [--only fig2,fig9,...]
 
 Default grids are strided for CPU wall-time; --full uses the paper's exact
-grids (273k+ problem configurations).
+grids (273k+ problem configurations). A suite that raises prints a
+``<suite>/ERROR`` row, the others still run, and the command exits non-zero.
 """
 import argparse
+import sys
 import time
+
+from repro.launch.compile_cache import enable_compile_cache
 
 from benchmarks import (bench_arch_fulcrum, bench_concurrent,
                         bench_concurrent_inference, bench_dynamic,
@@ -40,8 +44,10 @@ def main() -> None:
                     help="comma-separated suite names")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+    enable_compile_cache()
 
     print("name,value,derived")
+    failed = []
     for name, fn in SUITES.items():
         if only and name not in only:
             continue
@@ -51,7 +57,10 @@ def main() -> None:
                 print(row, flush=True)
         except Exception as e:  # keep the harness running
             print(f"{name}/ERROR,1,{type(e).__name__}: {e}", flush=True)
+            failed.append(name)
         print(f"{name}/wall_s,{time.time()-t0:.1f},", flush=True)
+    if failed:
+        sys.exit(f"failed suites: {','.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -27,6 +27,7 @@ from benchmarks.bench_dynamic import make_traces
 from repro.core.controller import ControllerConfig
 from repro.core.device_model import DeviceModel, INFER_WORKLOADS
 from repro.core.scheduler import Fulcrum
+from repro.launch.compile_cache import enable_compile_cache
 
 POWER, LATENCY = 40.0, 0.1
 
@@ -49,6 +50,7 @@ def main() -> None:
                          "cannot serve in budget, defer re-offers them at "
                          "the next window start")
     args = ap.parse_args()
+    enable_compile_cache()
     closed = args.closed_loop or args.admission != "none"
 
     rates = make_traces()[args.trace]

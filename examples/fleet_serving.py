@@ -23,6 +23,7 @@ import numpy as np
 from repro.core import fleet as F
 from repro.core.controller import ControllerConfig
 from repro.core.device_model import INFER_WORKLOADS
+from repro.launch.compile_cache import enable_compile_cache
 
 POWER, LATENCY = 30.0, 0.1
 
@@ -43,6 +44,7 @@ def main() -> None:
                     help="run each window as ONE compiled solve+simulate "
                          "launch (jax/pallas backends only)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.fused and args.sequential:
         ap.error("--fused fuses the batched step; drop --sequential")
 

@@ -23,6 +23,7 @@ from repro.core.backend import resolve_backend
 from repro.core.device_model import (DeviceModel, INFER_WORKLOADS,
                                      TRAIN_WORKLOADS)
 from repro.core.scheduler import Fulcrum
+from repro.launch.compile_cache import enable_compile_cache
 
 TENANTS = [
     # (infer workload, arrival rate req/s, peak-latency budget s)
@@ -45,6 +46,7 @@ def main() -> None:
                     help="execution-engine backend (default: resolve via "
                          "FULCRUM_ENGINE_BACKEND, falling back to numpy)")
     args = ap.parse_args()
+    enable_compile_cache()
     backend = resolve_backend(args.backend)
 
     dev = DeviceModel()

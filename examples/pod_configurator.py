@@ -13,6 +13,7 @@ import argparse
 from repro.configs import ARCH_IDS, get_config
 from repro.core.tpu_adapter import (GMDForTPU, RooflineTPUModel, TPUKnobSpace,
                                     exhaustive_best)
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -21,6 +22,7 @@ def main() -> None:
     ap.add_argument("--seq", type=int, default=4096)
     ap.add_argument("--batch", type=int, default=256)
     args = ap.parse_args()
+    enable_compile_cache()
 
     print(f"{'arch':16s} {'GMD config':>20s} {'t_step':>8s} {'HBM':>7s} "
           f"{'probes':>6s} {'vs oracle':>9s}")

@@ -13,6 +13,7 @@ from repro.configs import get_config, make_batch, reduced
 from repro.core import problem as P
 from repro.core.device_model import DeviceModel, workload_from_model_config
 from repro.core.scheduler import Fulcrum
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime.serving import GenerationServer
 from repro.runtime.train_loop import Trainer
 
@@ -22,6 +23,7 @@ def main() -> None:
     ap.add_argument("--arch", default="stablelm-1.6b")
     ap.add_argument("--steps", type=int, default=20)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = reduced(get_config(args.arch))
     print(f"== 1. training reduced {cfg.name} ({cfg.arch_type}) ==")

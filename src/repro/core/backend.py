@@ -41,7 +41,7 @@ window's whole point is driving it to 1) and lets tests pin it.
 from __future__ import annotations
 
 import os
-from typing import Optional, Sequence
+from typing import Callable, Optional, Sequence
 
 #: Environment variable consulted when no explicit backend is requested.
 ENGINE_BACKEND_ENV = "FULCRUM_ENGINE_BACKEND"
@@ -58,15 +58,24 @@ _PALLAS_MISSING_MSG = ("backend='pallas' requires jax.experimental.pallas; "
                        "use the 'jax' or default NumPy backend")
 
 
+def _installed(module: str) -> bool:
+    """True when ``module`` imports; False only when it is not installed.
+    Any other failure while importing it (a broken install, an API that
+    moved) propagates as itself rather than reading as "absent"."""
+    try:
+        __import__(module)
+    except ModuleNotFoundError as e:
+        if e.name is not None and (module + ".").startswith(e.name + "."):
+            return False
+        raise
+    return True
+
+
 def jax_available() -> bool:
     """True when jax imports; probed once and memoized."""
     global _JAX_OK
     if _JAX_OK is None:
-        try:
-            import jax  # noqa: F401
-            _JAX_OK = True
-        except Exception:
-            _JAX_OK = False
+        _JAX_OK = _installed("jax")
     return _JAX_OK
 
 
@@ -76,14 +85,7 @@ def pallas_available() -> bool:
     on CPU — no TPU needed; see ``src/repro/kernels/fulcrum/``)."""
     global _PALLAS_OK
     if _PALLAS_OK is None:
-        if not jax_available():
-            _PALLAS_OK = False
-        else:
-            try:
-                from jax.experimental import pallas  # noqa: F401
-                _PALLAS_OK = True
-            except Exception:
-                _PALLAS_OK = False
+        _PALLAS_OK = jax_available() and _installed("jax.experimental.pallas")
     return _PALLAS_OK
 
 
@@ -131,6 +133,13 @@ def record_dispatch(kind: str) -> None:
     _DISPATCH_COUNTS[kind] = _DISPATCH_COUNTS.get(kind, 0) + 1
 
 
+def with_program(run: Callable, program: Callable) -> Callable:
+    """Attach the jitted ``program`` behind the host wrapper ``run`` as
+    ``run.jitted``, so ahead-of-time compiles can lower it directly."""
+    run.jitted = program
+    return run
+
+
 def dispatch_count(kind: Optional[str] = None) -> int:
     """Compiled-program launches since import: one layer's count, or the
     total across layers (``kind=None``) — the number a serving loop's
@@ -142,11 +151,10 @@ def dispatch_count(kind: Optional[str] = None) -> int:
 
 def require_jax():
     """Import (jax, jax.numpy, enable_x64), raising the shared message when
-    jax is absent. The jax and Pallas kernel caches build through this."""
-    try:
-        import jax
-        import jax.numpy as jnp
-        from jax.experimental import enable_x64
-    except Exception as e:  # pragma: no cover - jax is baked into the image
-        raise RuntimeError(_JAX_MISSING_MSG) from e
-    return jax, jnp, enable_x64
+    jax is not installed. The jax and Pallas kernel caches build through
+    this; ``enable_x64()`` is a context manager that turns float64 on."""
+    if not jax_available():
+        raise RuntimeError(_JAX_MISSING_MSG)
+    import jax
+    import jax.numpy as jnp
+    return jax, jnp, jax.enable_x64

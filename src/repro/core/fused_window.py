@@ -56,7 +56,7 @@ from typing import Sequence
 
 import numpy as np
 
-from repro.core.backend import record_dispatch, require_jax
+from repro.core.backend import record_dispatch, require_jax, with_program
 from repro.core.grid_eval import ObservationGrid, device_grid_arrays
 from repro.core.simulate import _pow2
 
@@ -273,7 +273,7 @@ def _fused_kernel(trims: bool, max_bs: int):
                          *[jnp.asarray(a) for a in host_args])
             return tuple(np.asarray(r) for r in res)
 
-    _FUSED_CACHE[key] = run
+    _FUSED_CACHE[key] = with_program(run, kernel)
     return run
 
 

@@ -47,7 +47,8 @@ from typing import Optional, Sequence, Union
 import numpy as np
 
 from repro.core import problem as P
-from repro.core.backend import check_backend, record_dispatch, require_jax
+from repro.core.backend import (check_backend, record_dispatch, require_jax,
+                                with_program)
 from repro.core.device_model import (MAX_CORES, MAX_CPUF, MAX_GPUF, MAX_MEMF,
                                      DeviceModel, WorkloadProfile, _pert)
 from repro.core.powermode import PowerMode, PowerModeSpace
@@ -906,7 +907,7 @@ def _jax_kernels() -> dict:
             with enable_x64():
                 res = fn(*[jnp.asarray(a) for a in args])
             return tuple(np.asarray(r) for r in res)
-        return wrapped
+        return with_program(wrapped, fn)
 
     _JAX_CACHE.update({"train": x64(train_kernel),
                        "infer": x64(infer_kernel),

@@ -89,7 +89,8 @@ from typing import Callable, Optional, Sequence
 
 import numpy as np
 
-from repro.core.backend import record_dispatch, require_jax, resolve_backend
+from repro.core.backend import (record_dispatch, require_jax, resolve_backend,
+                                with_program)
 from repro.core.device_model import DeviceModel, WorkloadProfile
 from repro.core.powermode import PowerMode
 
@@ -730,7 +731,7 @@ def _jax_engine() -> Callable:
                                 jnp.asarray(clock))
         return np.asarray(c), np.asarray(trained)
 
-    _JAX_ENGINE_CACHE["managed"] = run
+    _JAX_ENGINE_CACHE["managed"] = with_program(run, kernel)
     return run
 
 

@@ -1,6 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede every other import: JAX locks the device count on first init.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# ^ MUST precede every other import: JAX locks the platform and the device
+# count on first init. The dry run compiles for 512 placeholder CPU devices
+# and never takes an accelerator, even on a host that has one.
 
 """Multi-pod dry-run: lower + compile every (architecture x input-shape) on
 the production meshes and extract roofline inputs from the compiled artifact.
@@ -111,8 +114,6 @@ def _compile_once(cfg, shape, mesh, **kw) -> tuple[dict, object]:
         rec["memory"] = {"error": repr(e)}
     try:
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):   # older jax returns [dict]
-            cost = cost[0] if cost else {}
         rec["cost"] = {k: float(v) for k, v in cost.items()
                        if isinstance(v, (int, float)) and k in
                        ("flops", "bytes accessed", "transcendentals")}

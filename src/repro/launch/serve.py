@@ -1,7 +1,10 @@
 """Serving launcher: batched prefill+decode requests against one arch.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-14b \
+  PYTHONPATH=src python -m repro.launch.serve --arch internvl2-1b \
       --requests 8 --prompt-len 32 --gen 16 [--reduced]
+
+Serves the architecture at its published size on the default device;
+``--reduced`` swaps in the 2-layer smoke variant (what fits a CPU run).
 """
 from __future__ import annotations
 
@@ -9,6 +12,7 @@ import argparse
 import time
 
 from repro.configs import get_config, make_batch, reduced as reduce_cfg
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime.serving import GenerationServer
 
 
@@ -19,8 +23,10 @@ def main() -> None:
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="2-layer smoke variant (default: published size)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -1,17 +1,20 @@
 """Training launcher.
 
-  PYTHONPATH=src python -m repro.launch.train --arch stablelm-1.6b \
-      --steps 50 [--reduced] [--batch 8 --seq 128] [--ckpt /tmp/ck.npz]
+  PYTHONPATH=src python -m repro.launch.train --arch mamba2-780m \
+      --steps 50 [--reduced] [--batch 8 --seq 128] [--ckpt ck.npz]
 
-On this CPU container only --reduced configs execute; the full-size configs
-are exercised through repro.launch.dryrun (lower+compile, no allocation).
-On a TPU fleet the same jitted step runs under make_production_mesh().
+Trains the architecture at its published widths and depth on the default
+device (one accelerator chip); ``--reduced`` swaps in the 2-layer,
+d_model-256 smoke variant of the same family, which is what fits a CPU
+run. Models too large for one chip are exercised through
+``repro.launch.dryrun`` (lower + compile on a placeholder mesh).
 """
 from __future__ import annotations
 
 import argparse
 
 from repro.configs import get_config, reduced as reduce_cfg
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim.adamw import AdamWConfig
 from repro.runtime.train_loop import Trainer
 
@@ -23,10 +26,12 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--lr", type=float, default=3e-4)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="2-layer smoke variant (default: published size)")
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--ckpt-every", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

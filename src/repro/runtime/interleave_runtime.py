@@ -1,5 +1,7 @@
 """Real-mode managed interleaving: Fulcrum's executor over actual jitted JAX
-steps (reduced models on CPU; identical control flow on a TPU host).
+steps on the default device — full-size models on a TPU chip
+(``chip_smoke.py`` phase e), the reduced variants in a CPU run; the control
+flow is the same on both.
 
 This is the wall-clock counterpart of the engine's managed kernel
 (``core.simulate``): one program owns the accelerator, alternating jitted

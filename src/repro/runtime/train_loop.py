@@ -33,8 +33,10 @@ class TrainReport:
 
 
 class Trainer:
-    """Single-process trainer (CPU smoke / examples). The production path is
-    the same train_step jitted with mesh shardings via launch.steps."""
+    """Single-device trainer: the launcher, the examples and the real-mode
+    runtime train through it, on one chip or on the CPU. The multi-chip
+    path is the same train_step jitted with mesh shardings via
+    launch.steps."""
 
     def __init__(self, cfg: M.ModelConfig, batch: int, seq_len: int,
                  opt_cfg: AdamWConfig = AdamWConfig(), seed: int = 0,
@@ -58,10 +60,14 @@ class Trainer:
 
     def step_minibatch(self) -> None:
         """One optimizer step on the next data batch — the unit the managed
-        interleave runtime schedules into inference slack."""
+        interleave runtime schedules into inference slack. Returns when the
+        step has finished on the device: an asynchronously dispatched step
+        would let the runtime queue more training than the slack holds,
+        and inference would then wait behind it."""
         batch = next(self.data)
         self.params, self.opt_state, _ = self.step_fn(
             self.params, self.opt_state, batch)
+        jax.block_until_ready(self.params)
         self.step += 1
 
     def train(self, num_steps: int, log_every: int = 10,
@@ -85,15 +91,13 @@ class Trainer:
         return TrainReport(self.step, losses, times)
 
     def train_minibatch_time(self, warmup: int = 2, iters: int = 3) -> float:
-        """Profile one training minibatch (used by the real-mode Fulcrum)."""
+        """Profile one training minibatch (used by the real-mode Fulcrum):
+        the mean time of ``step_minibatch``, the unit the runtime schedules.
+        Steps dispatched back to back without waiting measured 0.965 s each
+        on a TPU v5e where one waited-for step takes 0.581 s."""
         for _ in range(warmup):
-            batch = next(self.data)
-            self.params, self.opt_state, _ = self.step_fn(
-                self.params, self.opt_state, batch)
+            self.step_minibatch()
         t0 = self.clock.now()
         for _ in range(iters):
-            batch = next(self.data)
-            self.params, self.opt_state, _ = self.step_fn(
-                self.params, self.opt_state, batch)
-        jax.block_until_ready(self.params)
+            self.step_minibatch()
         return (self.clock.now() - t0) / iters

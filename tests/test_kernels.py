@@ -112,13 +112,12 @@ def test_tiled_matmul_block_invariance():
 # replay of the managed recurrence — tolerances per docs/exactness.md.
 # ---------------------------------------------------------------------------
 
-from repro.core.backend import require_jax
 from repro.kernels.fulcrum.lane_sort import lane_sort
 from repro.kernels.fulcrum.maxplus_scan import maxplus_scan
 from repro.kernels.fulcrum.ref import (lane_sort_ref, lane_violations_ref,
                                        maxplus_scan_ref)
 
-_, _, _enable_x64 = require_jax()
+_enable_x64 = jax.enable_x64
 ENG_TOL = dict(rtol=1e-9, atol=1e-8)
 
 

@@ -82,7 +82,7 @@ def test_microbatched_step_matches_single(mb):
 
 def test_tp_only_param_specs_drop_fsdp():
     cfg = get_config("stablelm-1.6b")
-    mesh = AbstractMesh((("data", 16), ("model", 16)))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     fsdp = S.param_specs(cfg, mesh, fsdp_on=True)
     tponly = S.param_specs(cfg, mesh, fsdp_on=False)
     flat_f = jax.tree.leaves(fsdp, is_leaf=lambda x: isinstance(x, P))

@@ -13,8 +13,7 @@ from repro.models.model import init_cache, init_params
 
 
 def _abstract_mesh(sizes, names):
-    """jax >= 0.4.36 takes a single ((name, size), ...) shape tuple."""
-    return AbstractMesh(tuple(zip(names, sizes)))
+    return AbstractMesh(tuple(sizes), tuple(names))
 
 
 def _meshes():

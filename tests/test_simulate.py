@@ -334,6 +334,23 @@ def test_jax_multi_tenant_single_call_matches_numpy():
 # backend selection + batched report builder
 # ---------------------------------------------------------------------------
 
+def test_backend_probe_reports_only_a_missing_module_as_absent(tmp_path,
+                                                              monkeypatch):
+    """A module that is not installed probes False; one that is installed
+    but breaks while importing (an API that moved) raises its own error,
+    not "requires jax"."""
+    (tmp_path / "moved_api_mod.py").write_text(
+        "from jax.experimental import no_such_name\n")
+    monkeypatch.syspath_prepend(str(tmp_path))
+    assert B._installed("jax")
+    assert not B._installed("no_such_module_anywhere")
+    with pytest.raises(ImportError, match="no_such_name"):
+        B._installed("moved_api_mod")
+    jax, jnp, enable_x64 = B.require_jax()
+    with enable_x64():
+        assert jnp.ones(1).dtype == jnp.float64
+
+
 def test_jax_backend_selection_defaults_to_numpy_when_unavailable(monkeypatch):
     """Regression: with jax absent the default path must degrade to the
     NumPy reference — env-var requests included — while an *explicit*
