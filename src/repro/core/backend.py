@@ -29,19 +29,22 @@ Selection rules:
 The reference-backend invariant (NumPy results are authoritative; jax and
 Pallas are cross-checked against them) is documented in ``docs/exactness.md``.
 
-This module also hosts the **host-dispatch counters**: every compiled-program
-launch (a host->accelerator synchronization point) is recorded here by the
-layer that made it — ``"solver"`` for the grid-solver kernels
-(``core.grid_eval``), ``"engine"`` for the max-plus scan runners
-(``core.simulate``, one per lane chunk), ``"fused"`` for the fused
-fleet-window program (``core.fused_window``). ``dispatch_count()`` lets
-benchmarks report dispatches-per-window as a tracked number (the fused
-window's whole point is driving it to 1) and lets tests pin it.
+This module also names the **host-dispatch counters** (kept in
+``repro.obs``): every compiled-program launch (a host->accelerator
+synchronization point) is recorded by the layer that made it —
+``"solver"`` for the grid-solver kernels (``core.grid_eval``), ``"engine"``
+for the max-plus scan runners (``core.simulate``, one per lane chunk),
+``"fused"`` for the fused fleet-window program (``core.fused_window``).
+``dispatch_count()`` lets benchmarks report dispatches-per-window as a
+tracked number (the fused window's whole point is driving it to 1) and lets
+tests pin it.
 """
 from __future__ import annotations
 
 import os
 from typing import Callable, Optional, Sequence
+
+from repro import obs
 
 #: Environment variable consulted when no explicit backend is requested.
 ENGINE_BACKEND_ENV = "FULCRUM_ENGINE_BACKEND"
@@ -122,15 +125,9 @@ def resolve_backend(backend: Optional[str] = None,
     return backend
 
 
-# compiled-program launches since import, by layer. Unlike the retrace
-# counters (trace-time side effects in grid_eval/simulate/fused_window),
-# these count *calls* — each one is a host boundary crossing.
-_DISPATCH_COUNTS: dict = {"solver": 0, "engine": 0, "fused": 0}
-
-
 def record_dispatch(kind: str) -> None:
     """Record one compiled-program launch of the given layer."""
-    _DISPATCH_COUNTS[kind] = _DISPATCH_COUNTS.get(kind, 0) + 1
+    obs.count("dispatch." + kind)
 
 
 def with_program(run: Callable, program: Callable) -> Callable:
@@ -144,9 +141,7 @@ def dispatch_count(kind: Optional[str] = None) -> int:
     """Compiled-program launches since import: one layer's count, or the
     total across layers (``kind=None``) — the number a serving loop's
     dispatches-per-window is measured from."""
-    if kind is not None:
-        return _DISPATCH_COUNTS.get(kind, 0)
-    return sum(_DISPATCH_COUNTS.values())
+    return obs.counted("dispatch" if kind is None else "dispatch." + kind)
 
 
 def require_jax():

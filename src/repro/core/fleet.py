@@ -83,6 +83,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.core import problem as P
 from repro.core.backend import resolve_backend
 from repro.core.controller import (AdmissionPolicy, ControllerConfig,
@@ -648,134 +649,142 @@ def _serve_fleet_fused(w: WorkloadProfile, power_budget: float,
     out: list[FleetWindowReport] = []
     from repro.core.scheduler import WindowReport
     for i, rate in enumerate(rates):
-        t0 = i * window_duration
-        agg = _window_trace(float(rate), i, window_duration, arrivals, seed)
-        n_mig = _migrate_backlog(state.devices, wts, t0) \
-            if spec.migrate_backlog else 0
-        n_def = state.pop_fleet_deferred() if adm.active else 0
-        carried = _backlog_counts(state.devices, cfg)
-        counts0 = carried if spec.dispatch == "least-backlog" else None
-        merged, dtr, own_dtr, def_counts, counts = _dispatch_fleet_window(
-            agg, n_def, t0, wts, counts0, K)
-        announced = float(rate) * shares
-        pbud = _fleet_power_budgets(spec, power_budget, prev_attr, K)
-        hi = state.plan_rates(announced, t0, window_duration)
-        est = state.plan_rates(announced, t0, window_duration,
-                               margin=1.0, pressure=False)
-        if cfg.burst_quantile > 0.0:
-            hi = np.maximum(hi, [P.burst_rate(e, window_duration,
-                                              cfg.burst_quantile)
-                                 for e in est])
-        bud = state.plan_budgets([latency_budget] * K)
-        nominal = np.full(K, float(latency_budget))
-        live = est > 0.0
-        # the engine-side carry-in, flattened: device d's effective arrival
-        # vector [carried pending, dispatched arrivals] and its pre-switch
-        # clock max(carried clock, t0) — window_carry_in minus the switch
-        # cost, which the program charges in-line from prev_mode
-        eff: list[np.ndarray] = []
-        n_carry = np.zeros(K, np.int64)
-        clock0 = np.full(K, float(t0))
-        for d in range(K):
-            st = state.devices[d]
-            if cfg.carry_backlog and st.carry is not None:
-                pend = np.asarray(st.carry.pending, np.float64)
-                clock0[d] = max(float(st.carry.clock), float(t0))
-                n_carry[d] = pend.size
-                eff.append(np.concatenate([pend, dtr[d].times])
-                           if pend.size else dtr[d].times)
-            else:
-                eff.append(dtr[d].times)
-        res = fused_fleet_window(grid, ts, ps, pbud, bud, nominal, est, hi,
-                                 live, prev_mode, eff, n_carry, clock0,
-                                 float(cfg.mode_switch_s), adm_budget,
-                                 adm.trims)
-        shed_d = np.zeros(K, np.int64)
-        def_out_d = np.zeros(K, np.int64)
-        sols: list = [None] * K
-        switches = np.zeros(K)
-        reps: list = [None] * K
-        for d in range(K):
-            if not res["solved"][d]:
-                if def_counts[d]:
-                    shed_d[d] += state.push_fleet_deferred(
-                        int(def_counts[d]))
-                state.observe_unserved(d, own_dtr[d], window_duration)
-                continue
-            sel = int(res["sel"][d])
-            sol = P.Solution(pm=grid.modes[sel], bs=int(grid.bs[sel]),
-                             time=float(res["lam"][d]),
-                             power=float(res["power"][d]))
-            sols[d] = sol
-            switches[d] = state.mode_switch(d, sol.pm)   # == res["switch"]
-            n_rej = int(res["n_rej"][d])
-            if n_rej:
-                if adm.mode == "defer":
-                    dropped = state.push_fleet_deferred(n_rej)
-                    def_out_d[d] = n_rej - dropped
-                    shed_d[d] = dropped
-                else:
-                    shed_d[d] = n_rej
-            bs = sol.bs
-            n_adm = int(res["n_adm"][d])
-            nb = int(res["n_batches"][d])
-            ctv = np.asarray(res["adm_times"][d][:n_adm], np.float64)
-            if adm.trims and n_rej:
-                # rebuilt exactly as _admit_fleet_device does: the admitted
-                # window arrivals follow the admitted carry prefix
-                nca = int(n_carry[d]) - int(res["n_carry_rej"][d])
-                run_tr = ArrivalTrace(ctv[nca:].copy(), dtr[d].duration,
-                                      dtr[d].kind)
-            else:
-                run_tr = dtr[d]
-            power = float(res["power"][d])
-            reps[d] = ExecutionReport(
-                "managed",
-                np.asarray(res["latencies"][d][:nb * bs], np.float64).copy(),
-                0, run_tr.duration, power, run_tr,
-                queue_state=QueueState(ctv[nb * bs:].copy(),
-                                       float(res["clock_out"][d])),
-                attributed_power=power if nb else 0.0)
-            prev_mode[d] = int(res["mode_id"][d])
-        _presort_reports([r for r in reps if r is not None])
-        device_reports: list = [None] * K
-        for d in range(K):
-            rep = reps[d]
-            offered = len(own_dtr[d])
-            if rep is None:
-                device_reports[d] = WindowReport(
-                    float(announced[d]), None, None,
-                    estimated_rate=float(est[d]),
-                    carried_requests=int(carried[d]),
-                    shed_requests=int(shed_d[d]),
-                    goodput=0.0 if offered else 1.0,
-                    offered_requests=offered)
-                continue
-            sol = sols[d]
-            gp = _goodput(rep, latency_budget, offered)
-            rep.goodput = gp
-            rep.shed_requests = int(shed_d[d])
-            rep.deferred_requests = int(def_out_d[d])
-            state.observe(d, own_dtr[d], rep, latency_budget,
-                          window_duration, rep.queue_state)
-            key = (sol.pm, sol.bs, sol.tau_tr)
-            device_reports[d] = WindowReport(
-                float(announced[d]), sol, rep,
-                estimated_rate=float(est[d]),
-                replanned=key != prev_keys[d],
-                mode_switch_s=float(switches[d]),
-                carried_requests=int(carried[d]),
-                shed_requests=int(shed_d[d]),
-                deferred_requests=int(def_out_d[d]), goodput=gp,
-                offered_requests=offered)
-            prev_keys[d] = key
-        out.append(_fleet_report(
-            rate, device_reports, merged, counts, latency_budget,
-            offered=len(agg), shed=int(shed_d.sum()),
-            deferred=int(def_out_d.sum()), migrated=n_mig,
-            power_budgets=pbud.copy()
-            if spec.fleet_power_budget is not None else None))
-        prev_attr = _attributed_by_device(device_reports)
+        with obs.span("fleet.window", i=i):
+            with obs.span("fleet.prepare"):
+                t0 = i * window_duration
+                agg = _window_trace(float(rate), i, window_duration,
+                                    arrivals, seed)
+                n_mig = _migrate_backlog(state.devices, wts, t0) \
+                    if spec.migrate_backlog else 0
+                n_def = state.pop_fleet_deferred() if adm.active else 0
+                carried = _backlog_counts(state.devices, cfg)
+                counts0 = carried if spec.dispatch == "least-backlog" else None
+                merged, dtr, own_dtr, def_counts, counts = \
+                    _dispatch_fleet_window(agg, n_def, t0, wts, counts0, K)
+                announced = float(rate) * shares
+                pbud = _fleet_power_budgets(spec, power_budget, prev_attr, K)
+                hi = state.plan_rates(announced, t0, window_duration)
+                est = state.plan_rates(announced, t0, window_duration,
+                                       margin=1.0, pressure=False)
+                if cfg.burst_quantile > 0.0:
+                    hi = np.maximum(hi, [P.burst_rate(e, window_duration,
+                                                      cfg.burst_quantile)
+                                         for e in est])
+                bud = state.plan_budgets([latency_budget] * K)
+                nominal = np.full(K, float(latency_budget))
+                live = est > 0.0
+                # the engine-side carry-in, flattened: device d's effective
+                # arrival vector [carried pending, dispatched arrivals] and
+                # its pre-switch clock max(carried clock, t0) —
+                # window_carry_in minus the switch cost, which the program
+                # charges in-line from prev_mode
+                eff: list[np.ndarray] = []
+                n_carry = np.zeros(K, np.int64)
+                clock0 = np.full(K, float(t0))
+                for d in range(K):
+                    st = state.devices[d]
+                    if cfg.carry_backlog and st.carry is not None:
+                        pend = np.asarray(st.carry.pending, np.float64)
+                        clock0[d] = max(float(st.carry.clock), float(t0))
+                        n_carry[d] = pend.size
+                        eff.append(np.concatenate([pend, dtr[d].times])
+                                   if pend.size else dtr[d].times)
+                    else:
+                        eff.append(dtr[d].times)
+            res = fused_fleet_window(grid, ts, ps, pbud, bud, nominal, est, hi,
+                                     live, prev_mode, eff, n_carry, clock0,
+                                     float(cfg.mode_switch_s), adm_budget,
+                                     adm.trims)
+            with obs.span("fleet.report"):
+                shed_d = np.zeros(K, np.int64)
+                def_out_d = np.zeros(K, np.int64)
+                sols: list = [None] * K
+                switches = np.zeros(K)
+                reps: list = [None] * K
+                for d in range(K):
+                    if not res["solved"][d]:
+                        if def_counts[d]:
+                            shed_d[d] += state.push_fleet_deferred(
+                                int(def_counts[d]))
+                        state.observe_unserved(d, own_dtr[d], window_duration)
+                        continue
+                    sel = int(res["sel"][d])
+                    sol = P.Solution(pm=grid.modes[sel], bs=int(grid.bs[sel]),
+                                     time=float(res["lam"][d]),
+                                     power=float(res["power"][d]))
+                    sols[d] = sol
+                    # == res["switch"]
+                    switches[d] = state.mode_switch(d, sol.pm)
+                    n_rej = int(res["n_rej"][d])
+                    if n_rej:
+                        if adm.mode == "defer":
+                            dropped = state.push_fleet_deferred(n_rej)
+                            def_out_d[d] = n_rej - dropped
+                            shed_d[d] = dropped
+                        else:
+                            shed_d[d] = n_rej
+                    bs = sol.bs
+                    n_adm = int(res["n_adm"][d])
+                    nb = int(res["n_batches"][d])
+                    ctv = np.asarray(res["adm_times"][d][:n_adm], np.float64)
+                    if adm.trims and n_rej:
+                        # rebuilt exactly as _admit_fleet_device does: the
+                        # admitted window arrivals follow the admitted carry
+                        # prefix
+                        nca = int(n_carry[d]) - int(res["n_carry_rej"][d])
+                        run_tr = ArrivalTrace(ctv[nca:].copy(),
+                                              dtr[d].duration, dtr[d].kind)
+                    else:
+                        run_tr = dtr[d]
+                    power = float(res["power"][d])
+                    reps[d] = ExecutionReport(
+                        "managed",
+                        np.asarray(res["latencies"][d][:nb * bs],
+                                   np.float64).copy(),
+                        0, run_tr.duration, power, run_tr,
+                        queue_state=QueueState(ctv[nb * bs:].copy(),
+                                               float(res["clock_out"][d])),
+                        attributed_power=power if nb else 0.0)
+                    prev_mode[d] = int(res["mode_id"][d])
+                _presort_reports([r for r in reps if r is not None])
+                device_reports: list = [None] * K
+                for d in range(K):
+                    rep = reps[d]
+                    offered = len(own_dtr[d])
+                    if rep is None:
+                        device_reports[d] = WindowReport(
+                            float(announced[d]), None, None,
+                            estimated_rate=float(est[d]),
+                            carried_requests=int(carried[d]),
+                            shed_requests=int(shed_d[d]),
+                            goodput=0.0 if offered else 1.0,
+                            offered_requests=offered)
+                        continue
+                    sol = sols[d]
+                    gp = _goodput(rep, latency_budget, offered)
+                    rep.goodput = gp
+                    rep.shed_requests = int(shed_d[d])
+                    rep.deferred_requests = int(def_out_d[d])
+                    state.observe(d, own_dtr[d], rep, latency_budget,
+                                  window_duration, rep.queue_state)
+                    key = (sol.pm, sol.bs, sol.tau_tr)
+                    device_reports[d] = WindowReport(
+                        float(announced[d]), sol, rep,
+                        estimated_rate=float(est[d]),
+                        replanned=key != prev_keys[d],
+                        mode_switch_s=float(switches[d]),
+                        carried_requests=int(carried[d]),
+                        shed_requests=int(shed_d[d]),
+                        deferred_requests=int(def_out_d[d]), goodput=gp,
+                        offered_requests=offered)
+                    prev_keys[d] = key
+                out.append(_fleet_report(
+                    rate, device_reports, merged, counts, latency_budget,
+                    offered=len(agg), shed=int(shed_d.sum()),
+                    deferred=int(def_out_d.sum()), migrated=n_mig,
+                    power_budgets=pbud.copy()
+                    if spec.fleet_power_budget is not None else None))
+                prev_attr = _attributed_by_device(device_reports)
     return out
 
 

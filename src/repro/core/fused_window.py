@@ -56,6 +56,7 @@ from typing import Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.core.backend import record_dispatch, require_jax, with_program
 from repro.core.grid_eval import ObservationGrid, device_grid_arrays
 from repro.core.simulate import _pow2
@@ -63,17 +64,13 @@ from repro.core.simulate import _pow2
 # one compiled program per (trims, max_bs) variant x jit shape bucket
 _FUSED_CACHE: dict = {}
 
-# retrace counter, bumped inside the traced program body (fires at compile
-# time only). Mirrors grid_eval.solver_trace_count / simulate.engine_trace_count.
-_TRACE_COUNTS = {"fleet": 0}
-
 # the admission slack, exactly controller._admit_mask's
 _ADMIT_EPS = 1e-12
 
 
 def fleet_trace_count() -> int:
     """Number of fused fleet-window (re)traces since import."""
-    return _TRACE_COUNTS["fleet"]
+    return obs.counted("trace.fleet")
 
 
 def grid_mode_ids(grid: ObservationGrid) -> np.ndarray:
@@ -154,113 +151,116 @@ def _fused_kernel(trims: bool, max_bs: int):
     def window(t, p, bsf, mode_ids, ts, ps, pb, bud, nom, est, hi, live,
                prev_mode, times, n_times, n_carry, clock0, switch_cost,
                adm_budget):
-        _TRACE_COUNTS["fleet"] += 1        # fires at trace time only
+        obs.count("trace.fleet")           # fires at trace time only
         inf = jnp.inf
-        tk = t[None, :] * ts[:, None]      # per-device scaled grid rows —
-        pk = p[None, :] * ps[:, None]      # the PerturbedDeviceModel law
+        with jax.named_scope("fleet_ladder"):
+            tk = t[None, :] * ts[:, None]      # per-device scaled grid rows —
+            pk = p[None, :] * ps[:, None]      # the PerturbedDeviceModel law
 
-        def rung(need, ar, b_h, b_l):
-            # one masked-argmin solve per device row, replaying
-            # grid_eval's fleet kernel (sustainable at b_h, objective and
-            # latency budget at the low rate ar). ``need`` — "some device
-            # is still unsolved at this rung" — wraps the whole (K x grid)
-            # plane in a lax.cond, so the program pays for exactly the
-            # rungs the host-masked loop would have launched: in steady
-            # state (rung 1 solves everyone) rungs 2-4 cost one branch
-            # predicate, not three dense solves
-            K_pad = ts.shape[0]
+            def rung(need, ar, b_h, b_l):
+                # one masked-argmin solve per device row, replaying
+                # grid_eval's fleet kernel (sustainable at b_h, objective and
+                # latency budget at the low rate ar). ``need`` — "some device
+                # is still unsolved at this rung" — wraps the whole (K x grid)
+                # plane in a lax.cond, so the program pays for exactly the
+                # rungs the host-masked loop would have launched: in steady
+                # state (rung 1 solves everyone) rungs 2-4 cost one branch
+                # predicate, not three dense solves
+                K_pad = ts.shape[0]
 
-            def solve(_):
-                lam = (bsf[None, :] - 1.0) / ar[:, None] + tk
-                feas = ((pk <= pb[:, None])
-                        & (tk <= bsf[None, :] / b_h[:, None])
-                        & (lam <= b_l[:, None]))
-                lam_sel = jnp.where(feas, lam, inf)
-                idx = jnp.argmin(lam_sel, axis=1)
-                lam_i = jnp.take_along_axis(lam_sel, idx[:, None],
-                                            axis=1)[:, 0]
-                return idx.astype(jnp.int32), feas.any(axis=1), lam_i
+                def solve(_):
+                    lam = (bsf[None, :] - 1.0) / ar[:, None] + tk
+                    feas = ((pk <= pb[:, None])
+                            & (tk <= bsf[None, :] / b_h[:, None])
+                            & (lam <= b_l[:, None]))
+                    lam_sel = jnp.where(feas, lam, inf)
+                    idx = jnp.argmin(lam_sel, axis=1)
+                    lam_i = jnp.take_along_axis(lam_sel, idx[:, None],
+                                                axis=1)[:, 0]
+                    return idx.astype(jnp.int32), feas.any(axis=1), lam_i
 
-            def skip(_):
-                return (jnp.zeros(K_pad, jnp.int32),
-                        jnp.zeros(K_pad, bool), jnp.full(K_pad, inf))
+                def skip(_):
+                    return (jnp.zeros(K_pad, jnp.int32),
+                            jnp.zeros(K_pad, bool), jnp.full(K_pad, inf))
 
-            return jax.lax.cond(need, solve, skip, operand=None)
+                return jax.lax.cond(need, solve, skip, operand=None)
 
-        # the PR-5 ladder: rung r's gate reproduces the host loop's
-        # "live & still-unsolved" mask at rung r; a rung nobody needs is
-        # skipped at runtime (its gate is identically False either way)
-        interval = live & (hi > est)
-        idx1, ok1, lam1 = rung(interval.any(), est, jnp.maximum(hi, est),
-                               bud)
-        g1 = interval & ok1
-        idx2, ok2, lam2 = rung((interval & ~g1).any(), hi, hi, bud)
-        g2 = interval & ~g1 & ok2
-        un12 = live & ~g1 & ~g2
-        idx3, ok3, lam3 = rung(un12.any(), est, est, bud)
-        g3 = un12 & ok3
-        idx4, ok4, lam4 = rung((un12 & ~g3 & (bud < nom)).any(), est, est,
-                               nom)
-        g4 = un12 & ~g3 & (bud < nom) & ok4
-        solved = g1 | g2 | g3 | g4
-        sel = jnp.where(g1, idx1, jnp.where(g2, idx2,
-                        jnp.where(g3, idx3, idx4)))
-        lam_sel = jnp.where(g1, lam1, jnp.where(g2, lam2,
-                            jnp.where(g3, lam3, lam4)))
+            # the PR-5 ladder: rung r's gate reproduces the host loop's
+            # "live & still-unsolved" mask at rung r; a rung nobody needs is
+            # skipped at runtime (its gate is identically False either way)
+            interval = live & (hi > est)
+            idx1, ok1, lam1 = rung(interval.any(), est, jnp.maximum(hi, est),
+                                   bud)
+            g1 = interval & ok1
+            idx2, ok2, lam2 = rung((interval & ~g1).any(), hi, hi, bud)
+            g2 = interval & ~g1 & ok2
+            un12 = live & ~g1 & ~g2
+            idx3, ok3, lam3 = rung(un12.any(), est, est, bud)
+            g3 = un12 & ok3
+            idx4, ok4, lam4 = rung((un12 & ~g3 & (bud < nom)).any(), est, est,
+                                   nom)
+            g4 = un12 & ~g3 & (bud < nom) & ok4
+            solved = g1 | g2 | g3 | g4
+            sel = jnp.where(g1, idx1, jnp.where(g2, idx2,
+                            jnp.where(g3, idx3, idx4)))
+            lam_sel = jnp.where(g1, lam1, jnp.where(g2, lam2,
+                                jnp.where(g3, lam3, lam4)))
 
-        bs_i = bsf[sel].astype(jnp.int32)
-        t_in = t[sel] * ts
-        p_out = p[sel] * ps
-        msel = mode_ids[sel]
-        switch = jnp.where(solved & (prev_mode >= 0) & (msel != prev_mode),
-                           switch_cost, 0.0)
-        clock_in = clock0 + switch
+            bs_i = bsf[sel].astype(jnp.int32)
+            t_in = t[sel] * ts
+            p_out = p[sel] * ps
+            msel = mode_ids[sel]
+            switch = jnp.where(solved & (prev_mode >= 0) & (msel != prev_mode),
+                               switch_cost, 0.0)
+            clock_in = clock0 + switch
 
         T = times.shape[1]
         iota = jnp.arange(T, dtype=jnp.int32)
-        if trims:
-            admit = jax.vmap(admit_lane)(times, n_times, bs_i, t_in,
-                                         adm_budget, clock_in)
-            admit = admit | ~solved[:, None]    # unsolved lanes: untouched
-            in_range = iota[None, :] < n_times[:, None]
-            rej = (~admit) & in_range
-            n_rej = rej.sum(axis=1, dtype=jnp.int32)
-            n_carry_rej = (rej & (iota[None, :] < n_carry[:, None])
-                           ).sum(axis=1, dtype=jnp.int32)
-            # admitted times are a nondecreasing subsequence: a stable sort
-            # against +inf IS the compaction the host path rebuilds
-            ctimes = jnp.sort(jnp.where(admit, times, inf), axis=1,
-                              stable=True)
-            n_adm = n_times - n_rej
-        else:
-            n_rej = jnp.zeros_like(n_times)
-            n_carry_rej = n_rej
-            ctimes = times
-            n_adm = n_times
+        with jax.named_scope("fleet_admission"):
+            if trims:
+                admit = jax.vmap(admit_lane)(times, n_times, bs_i, t_in,
+                                             adm_budget, clock_in)
+                admit = admit | ~solved[:, None]    # unsolved lanes: untouched
+                in_range = iota[None, :] < n_times[:, None]
+                rej = (~admit) & in_range
+                n_rej = rej.sum(axis=1, dtype=jnp.int32)
+                n_carry_rej = (rej & (iota[None, :] < n_carry[:, None])
+                               ).sum(axis=1, dtype=jnp.int32)
+                # admitted times are a nondecreasing subsequence: a stable sort
+                # against +inf IS the compaction the host path rebuilds
+                ctimes = jnp.sort(jnp.where(admit, times, inf), axis=1,
+                                  stable=True)
+                n_adm = n_times - n_rej
+            else:
+                n_rej = jnp.zeros_like(n_times)
+                n_carry_rej = n_rej
+                ctimes = times
+                n_adm = n_times
 
-        # batch-ready gather with a traced bs, then the max-plus scan —
-        # solve feeds simulate without leaving the program
-        bs_c = jnp.maximum(bs_i, 1)
-        nb = n_adm // bs_c
-        last = (iota[None, :] + 1) * bs_c[:, None] - 1
-        validb = iota[None, :] < nb[:, None]
-        ready = jnp.where(
-            validb,
-            jnp.take_along_axis(ctimes, jnp.clip(last, 0, T - 1), axis=1),
-            inf)
-        ex = jnp.where(validb, t_in[:, None], 0.0)
-        a, b = jax.lax.associative_scan(combine, (ex, ready + ex), axis=1)
-        comp = jnp.maximum(clock_in[:, None] + a, b)
-        bidx = jnp.clip(iota[None, :] // bs_c[:, None], 0, T - 1)
-        served = iota[None, :] < (nb * bs_c)[:, None]
-        lat = jnp.where(served,
-                        jnp.take_along_axis(comp, bidx, axis=1) - ctimes,
-                        inf)
-        clock_out = jnp.where(
-            nb > 0,
-            jnp.take_along_axis(comp, jnp.clip(nb - 1, 0, T - 1)[:, None],
-                                axis=1)[:, 0],
-            clock_in)
+        with jax.named_scope("fleet_engine"):
+            # batch-ready gather with a traced bs, then the max-plus scan —
+            # solve feeds simulate without leaving the program
+            bs_c = jnp.maximum(bs_i, 1)
+            nb = n_adm // bs_c
+            last = (iota[None, :] + 1) * bs_c[:, None] - 1
+            validb = iota[None, :] < nb[:, None]
+            ready = jnp.where(
+                validb,
+                jnp.take_along_axis(ctimes, jnp.clip(last, 0, T - 1), axis=1),
+                inf)
+            ex = jnp.where(validb, t_in[:, None], 0.0)
+            a, b = jax.lax.associative_scan(combine, (ex, ready + ex), axis=1)
+            comp = jnp.maximum(clock_in[:, None] + a, b)
+            bidx = jnp.clip(iota[None, :] // bs_c[:, None], 0, T - 1)
+            served = iota[None, :] < (nb * bs_c)[:, None]
+            lat = jnp.where(served,
+                            jnp.take_along_axis(comp, bidx, axis=1) - ctimes,
+                            inf)
+            clock_out = jnp.where(
+                nb > 0,
+                jnp.take_along_axis(comp, jnp.clip(nb - 1, 0, T - 1)[:, None],
+                                    axis=1)[:, 0],
+                clock_in)
         return (solved, sel, lam_sel, p_out, switch, clock_in, n_rej,
                 n_carry_rej, ctimes, n_adm, nb, lat, clock_out)
 
@@ -269,9 +269,11 @@ def _fused_kernel(trims: bool, max_bs: int):
     def run(grid_cols, mode_ids, *host_args):
         record_dispatch("fused")
         with enable_x64():
-            res = kernel(*grid_cols, mode_ids,
-                         *[jnp.asarray(a) for a in host_args])
-            return tuple(np.asarray(r) for r in res)
+            with obs.span("fleet.launch"):     # uploads and enqueue only
+                res = kernel(*grid_cols, mode_ids,
+                             *[jnp.asarray(a) for a in host_args])
+            with obs.span("fleet.fetch"):      # waits for the device
+                return tuple(np.asarray(r) for r in res)
 
     _FUSED_CACHE[key] = with_program(run, kernel)
     return run
@@ -301,34 +303,41 @@ def fused_fleet_window(grid: ObservationGrid, ts: np.ndarray, ps: np.ndarray,
     K = len(eff_times)
     K_pad = _pow2(K)
     T_pad = _pow2(max((len(v) for v in eff_times), default=0))
-    times = np.full((K_pad, T_pad), np.inf)
-    n_times = np.zeros(K_pad, np.int32)
-    for d, v in enumerate(eff_times):
-        times[d, :len(v)] = v
-        n_times[d] = len(v)
+    with obs.span("fleet.fused") as sp:
+        if sp:
+            sp.attrs.update(events=sum(len(v) for v in eff_times),
+                            slots=K_pad * T_pad, t_pad=T_pad, k_pad=K_pad)
+        with obs.span("fleet.pack"):
+            times = np.full((K_pad, T_pad), np.inf)
+            n_times = np.zeros(K_pad, np.int32)
+            for d, v in enumerate(eff_times):
+                times[d, :len(v)] = v
+                n_times[d] = len(v)
 
-    def pad1(v, fill, dtype=np.float64):
-        out = np.full(K_pad, fill, dtype)
-        out[:K] = v
-        return out
+            def pad1(v, fill, dtype=np.float64):
+                out = np.full(K_pad, fill, dtype)
+                out[:K] = v
+                return out
 
-    run = _fused_kernel(bool(trims), _grid_max_bs(grid))
-    (solved, sel, lam, power, switch, clock_in, n_rej, n_carry_rej,
-     ctimes, n_adm, nb, lat, clock_out) = run(
-        device_grid_arrays(grid), _device_mode_ids(grid),
-        pad1(ts, 1.0), pad1(ps, 1.0), pad1(pbud, 0.0), pad1(bud, np.inf),
-        pad1(nominal, np.inf), pad1(est, 0.0), pad1(hi, 0.0),
-        pad1(live, False, bool), pad1(prev_mode, -1, np.int32),
-        times, n_times, pad1(n_carry, 0, np.int32), pad1(clock0, 0.0),
-        np.float64(switch_cost), pad1(np.full(K, float(adm_budget)), 0.0))
-    mode_ids = grid_mode_ids(grid)
-    return {"solved": solved[:K], "sel": sel[:K], "lam": lam[:K],
-            "power": power[:K], "mode_id": mode_ids[sel[:K]],
-            "switch": switch[:K], "clock_in": clock_in[:K],
-            "n_rej": n_rej[:K], "n_carry_rej": n_carry_rej[:K],
-            "adm_times": ctimes[:K], "n_adm": n_adm[:K],
-            "n_batches": nb[:K], "latencies": lat[:K],
-            "clock_out": clock_out[:K]}
+            args = (pad1(ts, 1.0), pad1(ps, 1.0), pad1(pbud, 0.0),
+                    pad1(bud, np.inf), pad1(nominal, np.inf),
+                    pad1(est, 0.0), pad1(hi, 0.0), pad1(live, False, bool),
+                    pad1(prev_mode, -1, np.int32), times, n_times,
+                    pad1(n_carry, 0, np.int32), pad1(clock0, 0.0),
+                    np.float64(switch_cost),
+                    pad1(np.full(K, float(adm_budget)), 0.0))
+        run = _fused_kernel(bool(trims), _grid_max_bs(grid))
+        (solved, sel, lam, power, switch, clock_in, n_rej, n_carry_rej,
+         ctimes, n_adm, nb, lat, clock_out) = run(
+            device_grid_arrays(grid), _device_mode_ids(grid), *args)
+        mode_ids = grid_mode_ids(grid)
+        return {"solved": solved[:K], "sel": sel[:K], "lam": lam[:K],
+                "power": power[:K], "mode_id": mode_ids[sel[:K]],
+                "switch": switch[:K], "clock_in": clock_in[:K],
+                "n_rej": n_rej[:K], "n_carry_rej": n_carry_rej[:K],
+                "adm_times": ctimes[:K], "n_adm": n_adm[:K],
+                "n_batches": nb[:K], "latencies": lat[:K],
+                "clock_out": clock_out[:K]}
 
 
 def _grid_max_bs(grid: ObservationGrid) -> int:

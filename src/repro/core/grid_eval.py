@@ -46,6 +46,7 @@ from typing import Optional, Sequence, Union
 
 import numpy as np
 
+from repro import obs
 from repro.core import problem as P
 from repro.core.backend import (check_backend, record_dispatch, require_jax,
                                 with_program)
@@ -775,14 +776,10 @@ def _solve_multi_jax(problems, cand: "_MultiCandidates", pb, ar, lb, out,
 
 _JAX_CACHE: dict = {}
 
-# retrace counter, bumped inside the traced kernel bodies (fires at
-# compile time only). Mirrors simulate.engine_trace_count().
-_TRACE_COUNTS = {"solver": 0}
-
 
 def solver_trace_count() -> int:
     """Number of solver-kernel (re)traces since import (all six kernels)."""
-    return _TRACE_COUNTS["solver"]
+    return obs.counted("trace.solver")
 
 
 def device_grid_arrays(grid: ObservationGrid) -> tuple:
@@ -809,7 +806,7 @@ def _jax_kernels() -> dict:
 
     @jax.jit
     def train_kernel(t, p, budgets):
-        _TRACE_COUNTS["solver"] += 1           # fires at trace time only
+        obs.count("trace.solver")              # fires at trace time only
         def one(b):
             feas = p <= b
             masked = jnp.where(feas, t, jnp.inf)
@@ -818,7 +815,7 @@ def _jax_kernels() -> dict:
 
     @jax.jit
     def infer_kernel(t, p, bsf, pb, lb, ar):
-        _TRACE_COUNTS["solver"] += 1
+        obs.count("trace.solver")
         def one(b_p, b_l, b_a):
             lam = (bsf - 1.0) / b_a + t
             feas = (p <= b_p) & (t <= bsf / b_a) & (lam <= b_l)
@@ -828,7 +825,7 @@ def _jax_kernels() -> dict:
 
     @jax.jit
     def concurrent_kernel(t_in, bsf, t_tr, pmax, valid, pb, lb, ar):
-        _TRACE_COUNTS["solver"] += 1
+        obs.count("trace.solver")
         def one(b_p, b_l, b_a):
             cycle = bsf / b_a
             lam = (bsf - 1.0) / b_a + t_in
@@ -843,7 +840,7 @@ def _jax_kernels() -> dict:
 
     @jax.jit
     def fleet_kernel(t, p, bsf, pb, lb, ar, hi, ts, ps):
-        _TRACE_COUNTS["solver"] += 1
+        obs.count("trace.solver")
         def one(b_p, b_l, b_a, b_h, k_t, k_p):
             # device row: the base grid scaled by this device's (time,
             # power) factors — the PerturbedDeviceModel law
@@ -891,13 +888,13 @@ def _jax_kernels() -> dict:
 
     @jax.jit
     def multi_train_kernel(t_in, bsf, pmax, t_tr, wts, pb, ar, lb):
-        _TRACE_COUNTS["solver"] += 1
+        obs.count("trace.solver")
         return jax.vmap(lambda p, a, l: _multi_one(
             t_in, bsf, pmax, t_tr, wts, p, a, l))(pb, ar, lb)
 
     @jax.jit
     def multi_infer_kernel(t_in, bsf, pmax, wts, pb, ar, lb):
-        _TRACE_COUNTS["solver"] += 1
+        obs.count("trace.solver")
         return jax.vmap(lambda p, a, l: _multi_one(
             t_in, bsf, pmax, None, wts, p, a, l))(pb, ar, lb)
 

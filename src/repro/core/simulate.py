@@ -89,6 +89,7 @@ from typing import Callable, Optional, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.core.backend import (record_dispatch, require_jax, resolve_backend,
                                 with_program)
 from repro.core.device_model import DeviceModel, WorkloadProfile
@@ -672,15 +673,11 @@ _JAX_ENGINE_CACHE: dict = {}
 # _LANE_CHUNK x K_pad floats (~4 MB at K=64) however many lanes a sweep has
 _LANE_CHUNK = 8192
 
-# retrace counters: bumped inside traced function bodies, so they count
-# compilations (trace-time side effects), not calls. engine_trace_count()
-# lets tests pin the shape-bucketing no-retrace contract.
-_TRACE_COUNTS = {"engine": 0}
-
 
 def engine_trace_count() -> int:
-    """Number of scan-kernel (re)traces since import, across backends."""
-    return _TRACE_COUNTS["engine"]
+    """Number of scan-kernel (re)traces since import, across backends: it
+    lets tests pin the shape-bucketing no-retrace contract."""
+    return obs.counted("trace.engine")
 
 
 @contextlib.contextmanager
@@ -716,7 +713,7 @@ def _jax_engine() -> Callable:
         return c, fills.sum()
 
     def batch(ready, exec_t, t_tr, tau_cap, clock):
-        _TRACE_COUNTS["engine"] += 1           # fires at trace time only
+        obs.count("trace.engine")              # fires at trace time only
         return jax.vmap(one_lane)(ready, exec_t, t_tr, tau_cap, clock)
 
     # the padded event buffers are fresh per-call copies — donate them so
@@ -746,7 +743,7 @@ def _pallas_engine() -> Callable:
     from repro.kernels.fulcrum.maxplus_scan import maxplus_scan
 
     def batch(ready, exec_t, t_tr, tau_cap, clock):
-        _TRACE_COUNTS["engine"] += 1           # fires at trace time only
+        obs.count("trace.engine")              # fires at trace time only
         return maxplus_scan(ready, exec_t, t_tr, tau_cap, clock)
 
     kernel = jax.jit(batch, donate_argnums=(0, 1))

@@ -465,6 +465,7 @@ def _segsum(x: jax.Array) -> jax.Array:
     return jnp.where(mask, out, -jnp.inf)
 
 
+@jax.named_scope("ssd_chunked")
 def ssd_chunked(x, dt, A, B, C, chunk: int, initial_state=None):
     """Chunked SSD scan (Mamba2, state-space duality).
 

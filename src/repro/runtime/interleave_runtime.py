@@ -34,6 +34,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.core.simulate import ArrivalTrace, ExecutionReport, \
     MultiTenantReport, batch_ready_events
 from repro.runtime.clock import Clock, WallClock
@@ -109,17 +110,26 @@ class ManagedInterleaveRuntime:
         events = batch_ready_events(arrivals, self.bss)
         latencies: list[list[float]] = [[] for _ in traces]
         trained = 0
-        for ready, j, start in events:
-            # fill slack with training minibatches predicted to finish
-            # before the batch is ready (inference never queues)
-            while self.trainer and clock.now() + self.t_tr <= ready:
-                self.trainer.step_minibatch()
-                trained += 1
-            clock.sleep_until(ready)           # wait for the batch to form
-            self._infer(j)
-            done = clock.now()
-            latencies[j].extend(done - arrivals[j][i]
-                                for i in range(start, start + self.bss[j]))
+        with obs.span("runtime.run", planned_step_s=self.t_tr):
+            for k, (ready, j, start) in enumerate(events):
+                # fill slack with training minibatches predicted to finish
+                # before the batch is ready (inference never queues)
+                while self.trainer and clock.now() + self.t_tr <= ready:
+                    self.trainer.step_minibatch()
+                    trained += 1
+                with obs.span("runtime.wait") as sp:  # no step fits the slack
+                    t = clock.now() if sp else 0.0
+                    clock.sleep_until(ready)   # wait for the batch to form
+                    if sp:
+                        sp.attrs["wait_s"] = clock.now() - t
+                with obs.span("runtime.serve", k=k) as sp:
+                    if sp:
+                        sp.attrs["lag_s"] = clock.now() - ready
+                    self._infer(j)
+                done = clock.now()
+                latencies[j].extend(done - arrivals[j][i]
+                                    for i in range(start,
+                                                   start + self.bss[j]))
         duration = max(self.trace.duration, 1e-9)
         reports = [ExecutionReport("managed-real", lat, 0, duration,
                                    power=0.0, trace=tr)

@@ -8,6 +8,7 @@ from typing import Optional
 import jax
 import numpy as np
 
+from repro import obs
 from repro.checkpoint.checkpoint import restore_checkpoint, save_checkpoint
 from repro.data.pipeline import ShardedPrefetcher, SyntheticTokenSource
 from repro.models import model as M
@@ -64,10 +65,11 @@ class Trainer:
         step has finished on the device: an asynchronously dispatched step
         would let the runtime queue more training than the slack holds,
         and inference would then wait behind it."""
-        batch = next(self.data)
-        self.params, self.opt_state, _ = self.step_fn(
-            self.params, self.opt_state, batch)
-        jax.block_until_ready(self.params)
+        with obs.span("train.step"):
+            batch = next(self.data)
+            self.params, self.opt_state, _ = self.step_fn(
+                self.params, self.opt_state, batch)
+            jax.block_until_ready(self.params)
         self.step += 1
 
     def train(self, num_steps: int, log_every: int = 10,
