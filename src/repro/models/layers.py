@@ -488,16 +488,22 @@ def ssd_chunked(x, dt, A, B, C, chunk: int, initial_state=None):
     dA = dtc * A[None, None, None, :]            # (b,nc,l,h)
     dA_cs = jnp.cumsum(dA, axis=2)               # within-chunk cumsum
 
+    # Each contraction takes two operands over a real contracting index; the
+    # per-position scalars over (b,nc,l,h) scale an operand or the result.
+    # As extra einsum operands they let XLA form the (b,nc,l,h,p,n) outer
+    # product of the other two, a multiply-reduce on the TPU's vector unit in
+    # the forward and three more times in the backward, not a matmul.
+
     # intra-chunk (diagonal blocks)
     L = jnp.exp(_segsum(dA.transpose(0, 1, 3, 2)))               # (b,nc,h,l,l)
     scores = jnp.einsum("bclhn,bcshn->bchls", Ch, Bh)            # (b,nc,h,l,l)
-    y_diag = jnp.einsum("bchls,bchls,bcshp,bcsh->bclhp",
-                        scores, L, xc, dtc)
+    w = scores * L * dtc.transpose(0, 1, 3, 2)[:, :, :, None, :]  # (b,nc,h,l,s)
+    y_diag = jnp.einsum("bchls,bcshp->bclhp", w, xc)
 
     # chunk-final states
     decay_states = jnp.exp(dA_cs[:, :, -1:, :] - dA_cs)          # (b,nc,l,h)
-    states = jnp.einsum("bclhn,bclh,bclh,bclhp->bchpn",
-                        Bh, decay_states, dtc, xc)               # (b,nc,h,p,n)
+    xs = xc * (decay_states * dtc)[..., None]                    # (b,nc,l,h,p)
+    states = jnp.einsum("bclhn,bclhp->bchpn", Bh, xs)            # (b,nc,h,p,n)
 
     # inter-chunk recurrence
     chunk_decay = jnp.exp(dA_cs[:, :, -1, :])                    # (b,nc,h)
@@ -517,7 +523,8 @@ def ssd_chunked(x, dt, A, B, C, chunk: int, initial_state=None):
     prev_states = prev_states.transpose(1, 0, 2, 3, 4)           # (b,nc,h,p,n)
 
     state_decay = jnp.exp(dA_cs)                                 # (b,nc,l,h)
-    y_off = jnp.einsum("bclhn,bchpn,bclh->bclhp", Ch, prev_states, state_decay)
+    y_off = (jnp.einsum("bclhn,bchpn->bclhp", Ch, prev_states)
+             * state_decay[..., None])
 
     y = (y_diag + y_off).reshape(b, s, h, p)
     return y, final

@@ -1,5 +1,7 @@
 """Per-arch smoke tests (reduced configs) + serving-cache consistency."""
 import dataclasses
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -134,6 +136,87 @@ def test_ssd_chunked_matches_stepwise_recurrence():
     y_naive = np.stack(ys, axis=1)                                   # (b,s,h,p)
     np.testing.assert_allclose(np.asarray(y_chunk), y_naive, rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(final), hstate, rtol=1e-4, atol=1e-4)
+
+
+def _ssd_stepwise(x, dt, A, B, C, initial_state):
+    """Per-token SSD recurrence in jnp, differentiable; head i reads group
+    i // (h // g) of B and C."""
+    rep = x.shape[2] // B.shape[2]
+    Bh = jnp.repeat(B, rep, axis=2)                                  # (b,s,h,n)
+    Ch = jnp.repeat(C, rep, axis=2)
+
+    def step(hstate, inp):
+        xt, dtt, bt, ct = inp
+        decay = jnp.exp(dtt * A[None, :])[:, :, None, None]         # (b,h,1,1)
+        hstate = hstate * decay + (dtt[:, :, None, None] * xt[..., None]
+                                   * bt[:, :, None, :])
+        return hstate, jnp.einsum("bhpn,bhn->bhp", hstate, ct)
+
+    seq = [a.swapaxes(0, 1) for a in (x, dt, Bh, Ch)]
+    final, ys = jax.lax.scan(step, initial_state, seq)
+    return ys.swapaxes(0, 1), final
+
+
+def _ssd_inputs(key, b, s, h, p, g, n):
+    ks = jax.random.split(key, 6)
+    return (jax.random.normal(ks[0], (b, s, h, p)),
+            jax.nn.softplus(jax.random.normal(ks[1], (b, s, h))),
+            -jnp.exp(jax.random.normal(ks[2], (h,)) * 0.3),
+            jax.random.normal(ks[3], (b, s, g, n)),
+            jax.random.normal(ks[4], (b, s, g, n)),
+            jax.random.normal(ks[5], (b, h, p, n)))
+
+
+@pytest.mark.parametrize("n_groups", [1, 2])
+@pytest.mark.parametrize("n_chunks", [1, 3])
+def test_ssd_chunked_grad_matches_stepwise_recurrence(n_groups, n_chunks):
+    """Gradients of the chunked SSD scan, for x, dt, A, B, C and the initial
+    state, equal those of the per-token recurrence in float32."""
+    from repro.models.layers import ssd_chunked
+    b, h, p, n, chunk = 2, 4, 3, 5, 8
+    args = _ssd_inputs(jax.random.key(7), b, n_chunks * chunk, h, p,
+                       n_groups, n)
+    wy, wf = (jax.random.normal(k, shape) for k, shape in zip(
+        jax.random.split(jax.random.key(8)),
+        [(b, n_chunks * chunk, h, p), (b, h, p, n)]))
+
+    def loss(fn):
+        def f(*a):
+            y, final = fn(*a)
+            return jnp.sum(y * wy) + jnp.sum(final * wf)
+        return f
+
+    argnums = tuple(range(6))
+    got = jax.jit(jax.grad(loss(lambda x, dt, A, B, C, h0: ssd_chunked(
+        x, dt, A, B, C, chunk, initial_state=h0)), argnums))(*args)
+    want = jax.jit(jax.grad(loss(_ssd_stepwise), argnums))(*args)
+    for name, g_got, g_want in zip(["x", "dt", "A", "B", "C", "h0"], got, want):
+        scale = float(jnp.max(jnp.abs(g_want)))
+        np.testing.assert_allclose(np.asarray(g_got), np.asarray(g_want),
+                                   rtol=1e-4, atol=1e-5 * scale, err_msg=name)
+
+
+@pytest.mark.parametrize("n_groups", [1, 3])
+def test_ssd_chunked_grad_lowers_no_state_outer_product(n_groups):
+    """The lowered gradient of the chunked SSD holds no tensor as large as
+    the (b, s, h, p, n) outer product of B and x: every contraction stays a
+    matmul. The head repeat of B and C, (b, nc, l, g, rep, n), is smaller."""
+    from repro.models.layers import ssd_chunked
+    b, chunk, nc, h, p, n = 2, 8, 2, 3, 5, 7
+    s = nc * chunk
+    args = _ssd_inputs(jax.random.key(9), b, s, h, p, n_groups, n)[:5]
+
+    def loss(*a):
+        y, final = ssd_chunked(*a, chunk)
+        return jnp.sum(y) + jnp.sum(final)
+
+    text = jax.jit(jax.grad(loss, argnums=tuple(range(5)))).lower(*args).as_text()
+    shapes = re.findall(r"tensor<((?:\d+x)+)[a-z]", text)
+    assert shapes, "no ranked tensor found in the lowered text"
+    sizes = {shape: math.prod(int(d) for d in shape.rstrip("x").split("x"))
+             for shape in shapes}
+    too_large = {k: v for k, v in sizes.items() if v >= b * s * h * p * n}
+    assert not too_large, too_large
 
 
 def test_moe_aux_loss_and_capacity():
