@@ -5,10 +5,12 @@ Pure-functional JAX: every layer is an ``init(key, cfg) -> params`` plus an
 pjit/shard_map cleanly and checkpoint as flat npz.
 
 Blocks provided: RMS/LayerNorm, rotary embeddings, GQA attention (optional
-QKV bias, sliding window, KV cache with ring buffer), SwiGLU/GELU MLP,
-top-k MoE with capacity-factor dispatch (einsum form so GSPMD shards the
-expert axis), and the Mamba2 SSD mixer (chunked scan for train/prefill,
-O(1) recurrence for decode).
+QKV bias, sliding window, rotary off, KV cache with ring buffer),
+SwiGLU/GELU/squared-ReLU MLP, top-k MoE with capacity-factor dispatch
+(einsum form so GSPMD shards the expert axis), a dropless expert layer that
+holds a contiguous range of the routed experts (sigmoid router with a
+selection bias, grouped products, a shared expert), and the Mamba2 SSD
+mixer (chunked scan for train/prefill, O(1) recurrence for decode).
 """
 from __future__ import annotations
 
@@ -20,6 +22,8 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from repro import obs
 
 Params = dict
 DEFAULT_ROPE_THETA = 10_000.0
@@ -33,11 +37,19 @@ def rmsnorm_init(d: int) -> Params:
     return {"scale": jnp.ones((d,), jnp.float32)}
 
 
-def rmsnorm_apply(p: Params, x: jax.Array, eps: float = 1e-6) -> jax.Array:
+def rmsnorm_apply(p: Params, x: jax.Array, eps: float = 1e-6,
+                  groups: int = 1) -> jax.Array:
+    """RMSNorm over the last axis, or over each of ``groups`` equal slices of
+    it (Mamba-2's gated norm with several groups of B/C)."""
     dtype = x.dtype
     x = x.astype(jnp.float32)
+    if groups > 1:
+        shape = x.shape
+        x = x.reshape(*shape[:-1], groups, shape[-1] // groups)
     var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
     x = x * lax.rsqrt(var + eps)
+    if groups > 1:
+        x = x.reshape(shape)
     return (x * p["scale"]).astype(dtype)
 
 
@@ -115,6 +127,7 @@ class AttnSpec:
     sliding_window: Optional[int] = None
     rope_theta: float = DEFAULT_ROPE_THETA
     unroll: bool = False
+    rotary: bool = True             # False: no position embedding at all
 
 
 def attention_init(key, spec: AttnSpec) -> Params:
@@ -207,6 +220,7 @@ def _chunked_causal_attention(q, kt, vt, positions, scale, window, unroll=False)
     return out.transpose(1, 0, 2, 3, 4).reshape(b, s, hq, d)
 
 
+@jax.named_scope("attention")
 def attention_apply(
     p: Params,
     x: jax.Array,
@@ -229,8 +243,9 @@ def attention_apply(
     q = constrain(dense_apply(p["wq"], x).reshape(b, s, spec.n_heads, spec.head_dim))
     k = constrain(dense_apply(p["wk"], x).reshape(b, s, spec.n_kv_heads, spec.head_dim))
     v = constrain(dense_apply(p["wv"], x).reshape(b, s, spec.n_kv_heads, spec.head_dim))
-    q = apply_rope(q, positions, spec.rope_theta)
-    k = apply_rope(k, positions, spec.rope_theta)
+    if spec.rotary:
+        q = apply_rope(q, positions, spec.rope_theta)
+        k = apply_rope(k, positions, spec.rope_theta)
     scale = 1.0 / math.sqrt(spec.head_dim)
 
     if cache is None:
@@ -324,6 +339,8 @@ def mlp_apply(p: Params, x: jax.Array, activation: str = "swiglu") -> jax.Array:
     h = dense_apply(p["w1"], x)
     if activation == "swiglu":
         h = jax.nn.silu(h) * dense_apply(p["w3"], x)
+    elif activation == "relu2":
+        h = jnp.square(jax.nn.relu(h))
     else:
         h = jax.nn.gelu(h)
     return dense_apply(p["w2"], h)
@@ -414,6 +431,113 @@ def moe_apply(p: Params, x: jax.Array, spec: MoeSpec) -> tuple[jax.Array, jax.Ar
 
 
 # ---------------------------------------------------------------------------
+# Dropless expert layer over a held range of routed experts, with a shared
+# expert (DeepSeek-V3-style router: sigmoid affinities, a selection bias)
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class HeldMoeSpec:
+    d_model: int
+    d_ff: int                       # width of each routed expert
+    d_shared: int                   # width of the shared expert
+    n_experts: int                  # routed experts the router scores
+    top_k: int
+    held: int                       # experts this layer holds: ids
+    held_lo: int = 0                # [held_lo, held_lo + held)
+    scaling: float = 1.0            # routed_scaling_factor
+
+    @property
+    def rows(self) -> int:
+        """Token-choices one token can place on held experts, at most: the
+        grouped products' static rows per token."""
+        return min(self.top_k, self.held)
+
+
+def held_moe_init(key, spec: HeldMoeSpec) -> Params:
+    kr, ku, kd, ks = jax.random.split(key, 4)
+    d, f = spec.d_model, spec.d_ff
+    return {
+        "router": jax.random.normal(kr, (d, spec.n_experts), jnp.float32) / math.sqrt(d),
+        "router_bias": jnp.zeros((spec.n_experts,), jnp.float32),
+        "up": jax.random.normal(ku, (spec.held, d, f), jnp.float32) / math.sqrt(d),
+        "down": jax.random.normal(kd, (spec.held, f, d), jnp.float32) / math.sqrt(f),
+        "shared": mlp_init(ks, d, spec.d_shared, activation="relu2"),
+    }
+
+
+def held_moe_route(p: Params, x: jax.Array, spec: HeldMoeSpec):
+    """x: (T, d). Returns the chosen expert ids (T, k) over all
+    ``n_experts`` and their weights (T, k), float32: the top k by sigmoid
+    affinity plus ``router_bias`` (the bias selects, it does not weigh);
+    weights are the chosen unbiased affinities, normalised to sum to one,
+    times ``scaling``.
+
+    A layer that holds only a share of the experts passes no gradient
+    through the weights: theirs needs every chosen expert's output, and
+    those held elsewhere are absent here, so what reached the router would
+    turn it toward the experts this layer holds."""
+    logits = jnp.einsum("td,de->te", x.astype(jnp.float32), p["router"],
+                        precision=lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = lax.top_k(scores + p["router_bias"], spec.top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * spec.scaling
+    return idx, w if spec.held == spec.n_experts else lax.stop_gradient(w)
+
+
+def held_moe_apply(p: Params, x: jax.Array, spec: HeldMoeSpec
+                   ) -> tuple[jax.Array, dict]:
+    """Routed experts held here plus the shared expert, each
+    ``down(relu(up(x))^2)``. x: (B, S, d).
+
+    The router scores all ``n_experts``; only token-choices that land on
+    held experts are computed, none dropped: they are sorted by expert and
+    multiplied as grouped products (``lax.ragged_dot``) over a static bound
+    of ``T * rows`` rows, the held ones first. What the experts held
+    elsewhere would add is left out. Returns (y, counts): the token-choices
+    made, those on held experts, the most on one held expert, the grouped
+    products' static rows and the experts held, each an int32 scalar."""
+    obs.count("trace.moe")
+    b, s, d = x.shape
+    t, k, held = b * s, spec.top_k, spec.held
+    xt = x.reshape(t, d)
+    with jax.named_scope("moe_router"):
+        idx, w = held_moe_route(p, xt, spec)
+        local = (idx - spec.held_lo).reshape(-1)                # (T*k,)
+        on_held = (local >= 0) & (local < held)
+        local = jnp.where(on_held, local, held)                 # others last
+        order = jnp.argsort(local, stable=True)[:t * spec.rows]
+        sizes = jnp.bincount(local, length=held + 1)[:held].astype(jnp.int32)
+        row_w = jnp.where(on_held, w.reshape(-1), 0.0)[order]
+    with jax.named_scope("moe_experts"):
+        # the TPU's grouped product leaves the rows past its last group
+        # unwritten, and the gradient of its rows operand is such a product:
+        # both operands' rows past the held choices are zeroed going in, so
+        # what those rows hold never reaches a gradient
+        held_rows = (jnp.arange(order.shape[0]) < jnp.sum(sizes))[:, None]
+        rows = jnp.where(held_rows, jnp.take(xt, order // k, axis=0,
+                                             mode="clip"), 0)
+        h = jnp.square(jax.nn.relu(lax.ragged_dot(rows, p["up"].astype(x.dtype),
+                                                  sizes)))
+        h = jnp.where(held_rows, h * row_w[:, None].astype(h.dtype), 0)
+        out = lax.ragged_dot(h, p["down"].astype(x.dtype), sizes)
+        # back to (token, choice) order; a choice off the held range has no row
+        slot = jnp.zeros((t * k,), jnp.int32).at[order].set(
+            jnp.arange(order.shape[0], dtype=jnp.int32))
+        back = jnp.where(on_held[:, None],
+                         jnp.take(out, slot, axis=0, mode="clip"), 0)
+        y = jnp.sum(back.reshape(t, k, d).astype(jnp.float32), axis=1)
+    with jax.named_scope("moe_shared"):
+        y = y.astype(x.dtype) + mlp_apply(p["shared"], xt, "relu2")
+    counts = {"moe_choices": jnp.asarray(t * k, jnp.int32),
+              "moe_held": jnp.sum(sizes),
+              "moe_load_max": jnp.max(sizes),
+              "moe_rows": jnp.asarray(t * spec.rows, jnp.int32),
+              "moe_experts": jnp.asarray(held, jnp.int32)}
+    return y.reshape(b, s, d), counts
+
+
+# ---------------------------------------------------------------------------
 # Mamba2 / SSD mixer
 # ---------------------------------------------------------------------------
 
@@ -426,10 +550,12 @@ class SSMSpec:
     head_dim: int = 64
     n_groups: int = 1
     chunk: int = 256
+    heads: int = 0                  # 0: expand * d_model // head_dim
+    norm_eps: float = 1e-6          # the gated norm's epsilon
 
     @property
     def d_inner(self) -> int:
-        return self.expand * self.d_model
+        return self.heads * self.head_dim if self.heads else self.expand * self.d_model
 
     @property
     def n_heads(self) -> int:
@@ -594,7 +720,7 @@ def ssm_apply(p: Params, x: jax.Array, spec: SSMSpec,
 
     y = y + xi.astype(jnp.float32) * p["D"][None, None, :, None]
     y = y.reshape(b, s, din).astype(x.dtype)
-    y = rmsnorm_apply(p["norm"], y * jax.nn.silu(z))
+    y = rmsnorm_apply(p["norm"], y * jax.nn.silu(z), spec.norm_eps, spec.n_groups)
     out = dense_apply({"w": p["out_proj"]}, y)
     return out, new_cache
 

@@ -1,9 +1,12 @@
-"""Decoder-LM skeleton covering the six assigned architecture families.
+"""Decoder-LM skeleton covering the assigned architecture families.
 
 One config-driven model: dense / MoE / SSM (Mamba2-SSD) / hybrid (Zamba2) /
-VLM backbone / audio backbone. Homogeneous layer stacks are parameterised as
-leading-axis-stacked pytrees and executed with ``jax.lax.scan`` so HLO size is
-O(1) in depth (essential for 56-layer full-size dry-run compiles).
+VLM backbone / audio backbone / nemotron_h (a stack laid out by a pattern of
+Mamba-2, expert and attention blocks). Homogeneous layer stacks are
+parameterised as leading-axis-stacked pytrees and executed with
+``jax.lax.scan`` so HLO size is O(1) in depth (essential for 56-layer
+full-size dry-run compiles); a pattern stack keeps one stack per block kind
+and scans the pattern, switching on each layer's kind.
 
 Entry points:
   init_params(key, cfg)                      -> params
@@ -28,6 +31,12 @@ from repro.models import layers as L
 
 def round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
+
+
+# nemotron_h block kinds: Mamba-2, experts, attention; each kind's params
+# are stacked under its own key
+PATTERN_KINDS = ("M", "E", "*")
+PATTERN_STACKS = {"M": "mamba_layers", "E": "moe_layers", "*": "attn_layers"}
 
 
 # ---------------------------------------------------------------------------
@@ -68,7 +77,7 @@ def constrain(x: jax.Array) -> jax.Array:
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
-    arch_type: str                      # dense | moe | ssm | hybrid | vlm | audio
+    arch_type: str                      # dense | moe | ssm | hybrid | vlm | audio | nemotron_h
     num_layers: int
     d_model: int
     n_heads: int                        # 0 for attention-free (ssm)
@@ -77,10 +86,13 @@ class ModelConfig:
     vocab_size: int
     head_dim: int = 0                   # 0 -> d_model // n_heads
     norm: str = "rmsnorm"
+    norm_eps: float = 1e-6              # RMSNorm's, in nemotron_h and the SSM's gated norm
     activation: str = "swiglu"
     qkv_bias: bool = False
     sliding_window: Optional[int] = None      # training-time SWA (Mixtral)
     rope_theta: float = 10_000.0
+    rotary: bool = True
+    tie_embeddings: bool = True         # False: an output head of its own
     # MoE
     n_experts: int = 0
     top_k: int = 2
@@ -88,12 +100,22 @@ class ModelConfig:
     moe_group_size: int = 1024
     moe_dense_residual: bool = False
     moe_aux_weight: float = 0.01
+    # nemotron_h experts: routed over n_experts, ids [experts_held_lo,
+    # experts_held_lo + experts_held) held here (0: all), d_ff wide, plus a
+    # shared expert moe_shared_ff wide
+    experts_held: int = 0
+    experts_held_lo: int = 0
+    moe_shared_ff: int = 0
+    routed_scaling: float = 1.0
     # SSM
     ssm_state: int = 0
     ssm_chunk: int = 256
     ssm_expand: int = 2
     ssm_head_dim: int = 64
     ssm_groups: int = 1
+    ssm_heads: int = 0                  # 0: ssm_expand * d_model // ssm_head_dim
+    # nemotron_h: one character per layer, M (Mamba-2), E (experts), * (attention)
+    layer_pattern: str = ""
     # hybrid (Zamba2): shared attention block every `attn_every` SSM layers
     attn_every: int = 6
     # VLM stub frontend
@@ -129,7 +151,7 @@ class ModelConfig:
             d_model=self.d_model, n_heads=self.n_heads,
             n_kv_heads=self.n_kv_heads, head_dim=self.resolved_head_dim,
             qkv_bias=self.qkv_bias, sliding_window=self.sliding_window,
-            rope_theta=self.rope_theta, unroll=self.unroll)
+            rope_theta=self.rope_theta, unroll=self.unroll, rotary=self.rotary)
 
     @property
     def moe_spec(self) -> L.MoeSpec:
@@ -141,11 +163,25 @@ class ModelConfig:
             dense_residual_ff=self.d_ff)
 
     @property
+    def held_moe_spec(self) -> L.HeldMoeSpec:
+        return L.HeldMoeSpec(
+            d_model=self.d_model, d_ff=self.d_ff, d_shared=self.moe_shared_ff,
+            n_experts=self.n_experts, top_k=self.top_k,
+            held=self.experts_held or self.n_experts,
+            held_lo=self.experts_held_lo, scaling=self.routed_scaling)
+
+    @property
     def ssm_spec(self) -> L.SSMSpec:
         return L.SSMSpec(
             d_model=self.d_model, d_state=self.ssm_state,
             expand=self.ssm_expand, head_dim=self.ssm_head_dim,
-            n_groups=self.ssm_groups, chunk=self.ssm_chunk)
+            n_groups=self.ssm_groups, chunk=self.ssm_chunk,
+            heads=self.ssm_heads, norm_eps=self.norm_eps)
+
+    @property
+    def pattern_kinds(self) -> tuple[str, ...]:
+        """The block kinds of a nemotron_h pattern, in a fixed order."""
+        return tuple(k for k in PATTERN_KINDS if k in self.layer_pattern)
 
     @property
     def n_attn_sites(self) -> int:
@@ -154,10 +190,28 @@ class ModelConfig:
             return 0
         return len([i for i in range(self.num_layers) if i % self.attn_every == 0])
 
+    def _pattern_layer_params(self) -> dict:
+        """Parameters of one block of each nemotron_h kind, its norm included."""
+        d, hd, m, s = (self.d_model, self.resolved_head_dim,
+                       self.held_moe_spec, self.ssm_spec)
+        conv = s.d_inner + 2 * s.n_groups * s.d_state
+        return {
+            "M": d + d * (s.d_inner + conv + s.n_heads) + (s.d_conv + 1) * conv
+                 + 3 * s.n_heads + s.d_inner + s.d_inner * d,
+            "E": d + (d + 1) * m.n_experts + m.held * 2 * d * m.d_ff
+                 + 2 * d * m.d_shared,
+            "*": d + d * hd * (self.n_heads + 2 * self.n_kv_heads)
+                 + self.n_heads * hd * d,
+        }
+
     def param_count(self) -> int:
         """Analytic parameter count (embedding + stack + head)."""
         d, f, v = self.d_model, self.d_ff, self.padded_vocab
         hd = self.resolved_head_dim
+        if self.arch_type == "nemotron_h":
+            per = self._pattern_layer_params()
+            heads = 1 if self.tie_embeddings else 2
+            return sum(per[k] for k in self.layer_pattern) + heads * v * d + d
         per_layer = 0
         if self.arch_type in ("dense", "vlm", "audio"):
             attn = d * hd * (self.n_heads + 2 * self.n_kv_heads) + self.n_heads * hd * d
@@ -185,7 +239,16 @@ class ModelConfig:
         return total
 
     def active_param_count(self) -> int:
-        """Params touched per token (MoE: top-k experts only)."""
+        """Params touched per token (MoE: top-k experts only; nemotron_h:
+        of the routed experts held, the top_k x held / n_experts a token
+        places on them on average)."""
+        if self.arch_type == "nemotron_h":
+            m = self.held_moe_spec
+            expert = 2 * self.d_model * m.d_ff
+            n_e = self.layer_pattern.count("E")
+            routed = n_e * m.held * expert
+            active = n_e * m.top_k * m.held * expert / m.n_experts
+            return self.param_count() - routed + round(active)
         if self.arch_type != "moe":
             return self.param_count()
         d, f = self.d_model, self.d_ff
@@ -197,6 +260,21 @@ class ModelConfig:
 # ---------------------------------------------------------------------------
 # init
 # ---------------------------------------------------------------------------
+
+def _norm(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
+    """A nemotron_h RMSNorm, at the configured epsilon."""
+    return L.rmsnorm_apply(p, x, cfg.norm_eps)
+
+
+def _pattern_block_init(key, cfg: ModelConfig, kind: str) -> dict:
+    """Params of ONE nemotron_h block of ``kind`` (unstacked)."""
+    ln = L.norm_init(cfg.norm, cfg.d_model)
+    if kind == "M":
+        return {"ln": ln, "ssm": L.ssm_init(key, cfg.ssm_spec)}
+    if kind == "E":
+        return {"ln": ln, "moe": L.held_moe_init(key, cfg.held_moe_spec)}
+    return {"ln": ln, "attn": L.attention_init(key, cfg.attn_spec)}
+
 
 def _layer_init(key, cfg: ModelConfig) -> dict:
     """Params of ONE layer (unstacked)."""
@@ -236,13 +314,26 @@ def _apply_param_dtype(params: dict, cfg: ModelConfig) -> dict:
 
 def init_params(key, cfg: ModelConfig) -> dict:
     ke, kl, kx = jax.random.split(key, 3)
-    layer_keys = jax.random.split(kl, cfg.num_layers)
-    stack = jax.vmap(lambda k: _layer_init(k, cfg))(layer_keys)
     params = {
         "embed": L.embedding_init(ke, cfg.padded_vocab, cfg.d_model),
-        "layers": stack,
         "final_norm": L.norm_init(cfg.norm, cfg.d_model),
     }
+    if cfg.arch_type == "nemotron_h":
+        if len(cfg.layer_pattern) != cfg.num_layers:
+            raise ValueError(f"pattern {cfg.layer_pattern!r} has "
+                             f"{len(cfg.layer_pattern)} layers, not "
+                             f"{cfg.num_layers}")
+        for i, kind in enumerate(cfg.pattern_kinds):
+            keys = jax.random.split(jax.random.fold_in(kl, i),
+                                    cfg.layer_pattern.count(kind))
+            params[PATTERN_STACKS[kind]] = jax.vmap(
+                lambda k: _pattern_block_init(k, cfg, kind))(keys)
+    else:
+        layer_keys = jax.random.split(kl, cfg.num_layers)
+        params["layers"] = jax.vmap(lambda k: _layer_init(k, cfg))(layer_keys)
+    if not cfg.tie_embeddings:
+        params["head"] = L.embedding_init(jax.random.fold_in(ke, 1),
+                                          cfg.padded_vocab, cfg.d_model)
     if cfg.arch_type == "hybrid":
         k1, k2 = jax.random.split(kx)
         params["shared_attn"] = {
@@ -284,6 +375,8 @@ def embed_inputs(params: dict, batch: dict, cfg: ModelConfig) -> jax.Array:
 
 
 def output_logits(params: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
+    if cfg.arch_type == "nemotron_h":
+        return L.unembed_apply(params["head"], _norm(cfg, params["final_norm"], x))
     x = L.norm_apply(cfg.norm, params["final_norm"], x)
     if cfg.arch_type == "audio":
         outs = [L.unembed_apply(params["embed"], x)]
@@ -321,8 +414,73 @@ def _hybrid_shared(params, x, positions, cfg: ModelConfig, cache=None, cpos=None
     return x, kv
 
 
+MOE_COUNTS = ("moe_choices", "moe_held", "moe_load_max", "moe_rows",
+              "moe_experts")
+
+
+def _pattern_stack(params, x, positions, cfg: ModelConfig):
+    """The nemotron_h stack: ``x + mixer(norm(x))`` for each layer of the
+    pattern, scanned over the layers. Each step takes one block of every
+    kind's stack at that kind's rank so far and switches on the layer's
+    kind, so the program holds one block of each kind whatever the depth.
+    Returns (x, counts): the expert layers' routing counts, summed over
+    them (``moe_experts``, the experts each holds, is their most)."""
+    kinds = cfg.pattern_kinds
+    stacks = [params[PATTERN_STACKS[k]] for k in kinds]
+    pat = cfg.layer_pattern
+    kind_id = jnp.array([kinds.index(c) for c in pat], jnp.int32)
+    rank = jnp.array([[min(pat[:i].count(k), pat.count(k) - 1) for k in kinds]
+                      for i in range(len(pat))], jnp.int32)
+    zero = {k: jnp.zeros((), jnp.int32) for k in MOE_COUNTS}
+
+    def mamba(x, lp):
+        h, _ = L.ssm_apply(lp["ssm"], _norm(cfg, lp["ln"], x), cfg.ssm_spec)
+        return x + h, zero
+
+    def experts(x, lp):
+        h, counts = L.held_moe_apply(lp["moe"], _norm(cfg, lp["ln"], x),
+                                     cfg.held_moe_spec)
+        return x + h, counts
+
+    def attention(x, lp):
+        h, _ = L.attention_apply(lp["attn"], _norm(cfg, lp["ln"], x),
+                                 positions, cfg.attn_spec)
+        return x + h, zero
+
+    fns = {"M": mamba, "E": experts, "*": attention}
+    if cfg.remat:
+        # a switch's linearisation keeps every branch's residuals, zeros for
+        # the branches not taken: rematerialised, each keeps only its inputs
+        fns = {k: jax.checkpoint(f, prevent_cse=False) for k, f in fns.items()}
+
+    def body(x, inp):
+        x = constrain(x)
+        kid, r = inp
+        blocks = [jax.tree.map(lambda a: lax.dynamic_index_in_dim(
+            a, r[i], keepdims=False), st) for i, st in enumerate(stacks)]
+        branches = [lambda x, bl, i=i, k=k: fns[k](x, bl[i])
+                    for i, k in enumerate(kinds)]
+        return lax.switch(kid, branches, x, blocks)
+
+    if cfg.remat:
+        body = jax.checkpoint(body, prevent_cse=False)
+    x, counts = lax.scan(body, x, (kind_id, rank), unroll=cfg.unroll)
+    return x, {k: jnp.max(v) if k == "moe_experts" else jnp.sum(v)
+               for k, v in counts.items()}
+
+
+def _pattern_forward(params: dict, batch: dict, cfg: ModelConfig):
+    x = constrain(embed_inputs(params, batch, cfg))
+    b, s, _ = x.shape
+    positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
+    x, counts = _pattern_stack(params, x, positions, cfg)
+    return output_logits(params, x, cfg), counts
+
+
 def forward(params: dict, batch: dict, cfg: ModelConfig) -> tuple[jax.Array, jax.Array]:
     """Full-sequence forward (train / prefill). Returns (logits, moe_aux)."""
+    if cfg.arch_type == "nemotron_h":
+        return _pattern_forward(params, batch, cfg)[0], jnp.zeros((), jnp.float32)
     x = constrain(embed_inputs(params, batch, cfg))
     b, s, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
@@ -392,6 +550,13 @@ def softmax_xent(logits: jax.Array, labels: jax.Array) -> jax.Array:
 
 
 def train_loss(params: dict, batch: dict, cfg: ModelConfig) -> tuple[jax.Array, dict]:
+    """Mean next-token cross-entropy (plus the MoE auxiliary loss), and its
+    metrics; a nemotron_h stack adds its routing counts (``MOE_COUNTS``)."""
+    if cfg.arch_type == "nemotron_h":
+        logits, counts = _pattern_forward(params, batch, cfg)
+        xent = jnp.mean(softmax_xent(logits, batch["labels"]))
+        return xent, {"loss": xent, "xent": xent,
+                      "moe_aux": jnp.zeros((), jnp.float32), **counts}
     logits, aux = forward(params, batch, cfg)
     labels = batch["labels"]
     if cfg.arch_type == "vlm":
@@ -428,6 +593,7 @@ def prefill(params: dict, batch: dict, cfg: ModelConfig,
             max_seq_len: int, cache_dtype=jnp.bfloat16) -> tuple[jax.Array, dict]:
     """Process a full prompt; return (last-token logits, decode cache sized
     for a total context of max_seq_len)."""
+    _serves(cfg)
     if cfg.kv_cache_quant:
         cache_dtype = jnp.int8
     x = constrain(embed_inputs(params, batch, cfg))
@@ -532,6 +698,12 @@ def prefill(params: dict, batch: dict, cfg: ModelConfig,
 # decode (serving)
 # ---------------------------------------------------------------------------
 
+def _serves(cfg: ModelConfig) -> None:
+    if cfg.arch_type == "nemotron_h":
+        raise NotImplementedError(
+            "nemotron_h trains only: no prefill, cache or decode step")
+
+
 def cache_len_for(cfg: ModelConfig, seq_len: int) -> int:
     """KV ring-buffer length for a max context of seq_len.
 
@@ -549,6 +721,7 @@ def cache_len_for(cfg: ModelConfig, seq_len: int) -> int:
 
 def init_cache(cfg: ModelConfig, batch: int, seq_len: int, dtype=jnp.bfloat16) -> dict:
     """Decode cache for a maximum context of `seq_len` tokens."""
+    _serves(cfg)
     if cfg.kv_cache_quant:
         dtype = jnp.int8
     clen = cache_len_for(cfg, seq_len)
@@ -582,6 +755,7 @@ def decode_step(params: dict, cache: dict, batch: dict, pos: jax.Array,
                 cfg: ModelConfig) -> tuple[jax.Array, dict]:
     """One-token decode. batch['tokens']: (B,1) (or (B,1,CB) audio);
     pos: (B,) absolute positions. Returns (logits, new_cache)."""
+    _serves(cfg)
     x = constrain(embed_inputs(params, batch, cfg))    # (B, 1, d)
     positions = pos[:, None].astype(jnp.int32)
     spec = _effective_decode_spec(cfg)

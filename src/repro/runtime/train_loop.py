@@ -65,11 +65,14 @@ class Trainer:
         step has finished on the device: an asynchronously dispatched step
         would let the runtime queue more training than the slack holds,
         and inference would then wait behind it."""
-        with obs.span("train.step"):
+        with obs.span("train.step") as sp:
             batch = next(self.data)
-            self.params, self.opt_state, _ = self.step_fn(
+            self.params, self.opt_state, metrics = self.step_fn(
                 self.params, self.opt_state, batch)
             jax.block_until_ready(self.params)
+            if sp:   # the step's routing counts, where the model routes
+                sp.attrs.update({k: int(metrics[k]) for k in M.MOE_COUNTS
+                                 if k in metrics})
         self.step += 1
 
     def train(self, num_steps: int, log_every: int = 10,
