@@ -549,7 +549,7 @@ class SSMSpec:
     expand: int = 2
     head_dim: int = 64
     n_groups: int = 1
-    chunk: int = 256
+    chunk: int = 256                # the longest chunk the SSD scans
     heads: int = 0                  # 0: expand * d_model // head_dim
     norm_eps: float = 1e-6          # the gated norm's epsilon
 
@@ -656,6 +656,15 @@ def ssd_chunked(x, dt, A, B, C, chunk: int, initial_state=None):
     return y, final
 
 
+def ssd_chunk_len(s: int, longest: int) -> int:
+    """The chunk a row of ``s`` positions is scanned in: the fewest chunks of
+    at most ``longest`` rows, ``nc = ceil(s / longest)``, each ``ceil(s /
+    nc)`` rows rounded up to a multiple of 8; ``longest`` itself whenever it
+    divides ``s``."""
+    nc = -(-s // longest)
+    return min(longest, -(-s // (8 * nc)) * 8)
+
+
 def ssm_apply(p: Params, x: jax.Array, spec: SSMSpec,
               cache: Optional[Params] = None,
               return_state: bool = False) -> tuple[jax.Array, Optional[Params]]:
@@ -692,8 +701,12 @@ def ssm_apply(p: Params, x: jax.Array, spec: SSMSpec,
     A = -jnp.exp(p["A_log"])                                     # (H,)
 
     if cache is None:
-        # pad seq to a chunk multiple; dt=0 on pad => state unaffected
-        pad_s = (-s) % spec.chunk
+        # the fewest chunks of at most spec.chunk rows, each as short as s
+        # allows; pad seq to their multiple; dt=0 on pad => state unaffected
+        chunk = ssd_chunk_len(s, spec.chunk)
+        if chunk < spec.chunk:
+            obs.count("trace.ssd_chunk_cut")
+        pad_s = (-s) % chunk
         if pad_s:
             padf = lambda a: jnp.pad(a, [(0, 0), (0, pad_s)] + [(0, 0)] * (a.ndim - 2))
             xi_p, dt_p, B_p, C_p = padf(xi), padf(dt), padf(Bm), padf(Cm)
@@ -701,7 +714,7 @@ def ssm_apply(p: Params, x: jax.Array, spec: SSMSpec,
             xi_p, dt_p, B_p, C_p = xi, dt, Bm, Cm
         y, final_state = ssd_chunked(
             xi_p.astype(jnp.float32), dt_p, A,
-            B_p.astype(jnp.float32), C_p.astype(jnp.float32), spec.chunk)
+            B_p.astype(jnp.float32), C_p.astype(jnp.float32), chunk)
         y = y[:, :s]
         new_cache = ({"conv": new_conv, "ssm": final_state} if return_state else None)
     else:

@@ -219,6 +219,95 @@ def test_ssd_chunked_grad_lowers_no_state_outer_product(n_groups):
     assert not too_large, too_large
 
 
+def _ssm_block(n_groups, chunk=32):
+    """A small Mamba-2 block with no dimension equal to ``chunk``."""
+    from repro.models.layers import SSMSpec, ssm_init
+    spec = SSMSpec(d_model=12, d_state=8, head_dim=4, n_groups=n_groups,
+                   chunk=chunk)
+    return spec, ssm_init(jax.random.key(11), spec)
+
+
+@pytest.mark.parametrize("n_groups", [1, 2])
+@pytest.mark.parametrize("s", [12, 24, 40])
+def test_ssm_apply_short_chunk_matches_padding_to_the_chunk(s, n_groups,
+                                                            monkeypatch):
+    """``ssm_apply`` scans a row shorter than, or not a multiple of,
+    ``spec.chunk`` in chunks cut to the row (40 rows: two of 24). Its output,
+    final state and the gradients of the parameters and the input equal
+    those of padding the row to a multiple of ``spec.chunk``."""
+    from repro.models import layers as L
+    spec, params = _ssm_block(n_groups)
+    x = jax.random.normal(jax.random.key(12), (2, s, spec.d_model))
+    wy, wc, ws = (jax.random.normal(k, shape) for k, shape in zip(
+        jax.random.split(jax.random.key(13), 3),
+        [(2, s, spec.d_model), (2, spec.d_conv - 1, spec.d_inner
+                                + 2 * n_groups * spec.d_state),
+         (2, spec.n_heads, spec.head_dim, spec.d_state)]))
+
+    def run(p, x):
+        y, cache = L.ssm_apply(p, x, spec, return_state=True)
+        loss = (jnp.sum(y * wy) + jnp.sum(cache["conv"] * wc)
+                + jnp.sum(cache["ssm"] * ws))
+        return loss, (y, cache["ssm"])
+
+    def call():
+        return jax.jit(jax.value_and_grad(run, argnums=(0, 1),
+                                          has_aux=True))(params, x)
+
+    assert L.ssd_chunk_len(s, spec.chunk) < spec.chunk
+    (_, got), got_grads = call()
+    monkeypatch.setattr(L, "ssd_chunk_len", lambda s, longest: longest)
+    (_, want), want_grads = call()
+    leaves = jax.tree_util.tree_leaves_with_path(
+        (got, got_grads, want, want_grads))
+    n = len(leaves) // 2
+    for (path, g), (_, w) in zip(leaves[:n], leaves[n:]):
+        scale = float(jnp.max(jnp.abs(w)))
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
+                                   atol=1e-5 * scale,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
+def test_ssm_apply_grad_at_a_short_row_lowers_no_full_chunk():
+    """At 16 rows the gradient of ``ssm_apply`` holds no (..., 32, 32)
+    tensor of the 32-row chunk; at 32 rows it does. The chunk is
+    ``spec.chunk`` whenever the row is a multiple of it."""
+    from repro.models.layers import ssd_chunk_len, ssm_apply
+    spec, params = _ssm_block(1)
+
+    def lowered(s):
+        x = jnp.ones((2, s, spec.d_model))
+        grad = jax.jit(jax.grad(lambda p, x: jnp.sum(ssm_apply(p, x, spec)[0]),
+                                argnums=(0, 1)))
+        text = grad.lower(params, x).as_text()
+        return {tuple(int(d) for d in m.rstrip("x").split("x"))
+                for m in re.findall(r"tensor<((?:\d+x)+)[a-z]", text)}
+
+    full = lambda shapes: {t for t in shapes if t[-2:] == (32, 32)}
+    assert full(lowered(32))
+    assert not full(lowered(16)), full(lowered(16))
+    for longest in (8, 32, 128, 256):
+        for s in range(longest, 4 * longest + 1, longest):
+            assert ssd_chunk_len(s, longest) == longest
+
+
+def test_ssd_chunk_cut_counts_each_trace_of_a_shortened_chunk():
+    from repro import obs
+    from repro.models.layers import ssm_apply
+    spec, params = _ssm_block(1)
+
+    def traces(s):
+        f = jax.jit(lambda p, x: ssm_apply(p, x, spec)[0])
+        before = obs.counted("trace.ssd_chunk_cut")
+        for _ in range(2):
+            f(params, jnp.ones((2, s, spec.d_model))).block_until_ready()
+        return obs.counted("trace.ssd_chunk_cut") - before
+
+    assert traces(16) == 1
+    assert traces(32) == 0
+    assert traces(64) == 0
+
+
 def test_moe_aux_loss_and_capacity():
     """MoE: balanced routing gives aux ~1; capacity drops are bounded."""
     from repro.models.layers import MoeSpec, moe_apply, moe_init
