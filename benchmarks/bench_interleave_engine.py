@@ -13,7 +13,7 @@ mirroring bench_solver's BENCH_solver.json.
 
 The ``lane_scaling`` section sweeps the lane axis — 10 to 100k concurrent
 managed lanes sharing one short trace — through ``simulate_batch`` on every
-engine backend (numpy / jax / pallas), recording configs/s per backend so
+engine backend (numpy / jax), recording configs/s per backend so
 the NumPy-vs-accelerator crossover is a measured curve, not folklore.
 ``--quick`` caps the sweep at 1k lanes and snapshots to
 ``BENCH_interleave_partial.json`` (the committed full snapshot stays
@@ -27,7 +27,7 @@ from pathlib import Path
 import numpy as np
 
 from repro.core import simulate as S
-from repro.core.backend import jax_available, pallas_available
+from repro.core.backend import jax_available
 
 from benchmarks.bench_interleaving import solve_configs
 from benchmarks.common import DEV, row, snapshot
@@ -68,8 +68,6 @@ def _lane_scaling(w_tr, w_in, solved, lane_counts) -> dict:
     backends = ["numpy"]
     if jax_available():
         backends.append("jax")
-    if pallas_available():
-        backends.append("pallas")
     rows = []
     for lanes in lane_counts:
         pml = [pms[i % len(pms)] for i in range(lanes)]
@@ -192,7 +190,7 @@ def run(full: bool = False, quick: bool = False) -> list[str]:
                         f"numpy={numpy_s*1e3:.1f}ms;jax={jax_s*1e3:.1f}ms;"
                         f"n={len(solved)}"))
 
-    # -- lane scaling: the NumPy-vs-jax-vs-Pallas crossover curve ------------
+    # -- lane scaling: the NumPy-vs-jax crossover curve ----------------------
     lane_counts = QUICK_LANE_COUNTS if quick else LANE_COUNTS
     results["lane_scaling"] = _lane_scaling(w_tr, w_in, solved, lane_counts)
     for rec in results["lane_scaling"]["rows"]:

@@ -14,7 +14,7 @@ CSV and snapshotted to ``benchmarks/results/BENCH_multi_tenant.json``.
 The ``lane_scaling`` section mirrors bench_interleave_engine's: 10 to 100k
 two-stream multi-tenant lanes (shared traces) through
 ``simulate_multi_tenant_batch`` on every engine backend, recording the
-NumPy-vs-jax-vs-Pallas configs/s crossover on the N-stream path.
+NumPy-vs-jax configs/s crossover on the N-stream path.
 """
 from __future__ import annotations
 
@@ -25,7 +25,7 @@ import numpy as np
 
 from repro.core import problem as P
 from repro.core import simulate as S
-from repro.core.backend import jax_available, pallas_available
+from repro.core.backend import jax_available
 from repro.core.device_model import INFER_WORKLOADS, TRAIN_WORKLOADS
 from repro.core.scheduler import Fulcrum
 
@@ -91,8 +91,6 @@ def _lane_scaling(lane_counts=LANE_COUNTS) -> dict:
     backends = ["numpy"]
     if jax_available():
         backends.append("jax")
-    if pallas_available():
-        backends.append("pallas")
     rows = []
     for lanes in lane_counts:
         args = (DEV, w_tr, [streams] * lanes,

@@ -8,7 +8,7 @@ multipliers (``fleet_device``), governed by its own closed-loop controller
 state (EWMA rate estimate, latency feedback, backlog carryover). The
 batched step is bitwise-identical on NumPy to serving the K devices one by
 one with the existing single-device loop — ``--sequential`` runs that
-reference instead so the two can be diffed. ``--fused`` (jax/pallas only)
+reference instead so the two can be diffed. ``--fused`` (jax only)
 collapses each window further: the whole plan ladder + admission + engine
 runs as ONE compiled launch per window (``core.fused_window``), and the
 per-window host-dispatch count is printed from the backend counters.
@@ -36,13 +36,13 @@ def main() -> None:
     ap.add_argument("--dispatch", default="capacity",
                     choices=["capacity", "least-backlog"])
     ap.add_argument("--backend", default=None,
-                    help="engine backend (numpy/jax/pallas; default env)")
+                    help="engine backend (numpy/jax; default env)")
     ap.add_argument("--sequential", action="store_true",
                     help="run the K-sequential-loops reference instead of "
                          "the batched fleet step")
     ap.add_argument("--fused", action="store_true",
                     help="run each window as ONE compiled solve+simulate "
-                         "launch (jax/pallas backends only)")
+                         "launch (jax backend only)")
     args = ap.parse_args()
     enable_compile_cache()
     if args.fused and args.sequential:

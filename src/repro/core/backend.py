@@ -1,4 +1,4 @@
-"""Shared NumPy/jax/Pallas backend plumbing for the batched engines.
+"""Shared NumPy/jax backend plumbing for the batched engines.
 
 The vectorized layers — the grid-evaluation solvers (``core.grid_eval``) and
 the trace-driven execution engine (``core.simulate``) — share a backend
@@ -6,28 +6,23 @@ vocabulary resolved here so every entry point behaves identically:
 
  * ``"numpy"``  — the reference implementation, always available.
  * ``"jax"``    — jit + vmap programs, runs on-accelerator.
- * ``"pallas"`` — the engine's hand-written Pallas kernels
-   (``src/repro/kernels/fulcrum/``); engine-only — the grid solvers accept
-   ``numpy``/``jax`` (their masked reductions have no hand-written kernel).
 
 Selection rules:
 
- * ``check_backend``     — validate an explicit backend name against the
-   caller's allowed set.
- * ``jax_available`` / ``pallas_available`` — cached import probes;
-   monkeypatchable in tests.
+ * ``check_backend``     — validate an explicit backend name.
+ * ``jax_available``     — cached import probe; monkeypatchable in tests.
  * ``resolve_backend``   — map a request (``None`` / a backend name) to the
    backend that will actually run. ``None`` defers to the
    ``FULCRUM_ENGINE_BACKEND`` environment variable and **defaults to NumPy**;
-   an env-var request degrades down the tier order pallas → jax → numpy when
-   the requested tier is missing (the default path must never fail), while an
-   *explicit* backend argument raises, so a caller that asked for an
-   accelerator tier is told it is absent.
+   an env-var request for jax degrades to numpy when jax is missing (the
+   default path must never fail), while an *explicit* ``backend="jax"``
+   argument raises, so a caller that asked for the accelerator tier is told
+   it is absent. An unknown name raises ``ValueError`` either way.
  * ``require_jax``       — the lazy jax import used by the jax kernels, with
    one shared error message.
 
-The reference-backend invariant (NumPy results are authoritative; jax and
-Pallas are cross-checked against them) is documented in ``docs/exactness.md``.
+The reference-backend invariant (NumPy results are authoritative; jax is
+cross-checked against them) is documented in ``docs/exactness.md``.
 
 This module also names the **host-dispatch counters** (kept in
 ``repro.obs``): every compiled-program launch (a host->accelerator
@@ -42,23 +37,20 @@ tests pin it.
 from __future__ import annotations
 
 import os
-from typing import Callable, Optional, Sequence
+from typing import Callable, Optional
 
 from repro import obs
 
 #: Environment variable consulted when no explicit backend is requested.
 ENGINE_BACKEND_ENV = "FULCRUM_ENGINE_BACKEND"
 
-#: Engine tiers, fastest-intent first; resolve_backend degrades rightward.
-BACKEND_TIERS = ("pallas", "jax", "numpy")
+#: Backend tiers, accelerator first; resolve_backend degrades rightward.
+BACKEND_TIERS = ("jax", "numpy")
 
-_JAX_OK: Optional[bool] = None      # memoized import probes (tests patch)
-_PALLAS_OK: Optional[bool] = None
+_JAX_OK: Optional[bool] = None      # memoized import probe (tests patch)
 
 _JAX_MISSING_MSG = ("backend='jax' requires jax; "
                     "use the default NumPy backend")
-_PALLAS_MISSING_MSG = ("backend='pallas' requires jax.experimental.pallas; "
-                       "use the 'jax' or default NumPy backend")
 
 
 def _installed(module: str) -> bool:
@@ -82,23 +74,11 @@ def jax_available() -> bool:
     return _JAX_OK
 
 
-def pallas_available() -> bool:
-    """True when the Pallas kernel tier can run: jax imports *and*
-    ``jax.experimental.pallas`` is present (interpret mode makes it runnable
-    on CPU — no TPU needed; see ``src/repro/kernels/fulcrum/``)."""
-    global _PALLAS_OK
-    if _PALLAS_OK is None:
-        _PALLAS_OK = jax_available() and _installed("jax.experimental.pallas")
-    return _PALLAS_OK
-
-
-def check_backend(backend: str,
-                  allowed: Sequence[str] = BACKEND_TIERS) -> None:
-    """Validate an explicit backend name against the caller's allowed set
-    (the grid solvers pass ``("numpy", "jax")`` — no Pallas solver tier)."""
-    if backend not in allowed:
-        raise ValueError(f"unknown backend {backend!r}; "
-                         f"use one of {'/'.join(repr(a) for a in allowed)}")
+def check_backend(backend: str) -> None:
+    """Validate an explicit backend name against ``BACKEND_TIERS``."""
+    if backend not in BACKEND_TIERS:
+        raise ValueError(f"unknown backend {backend!r}; use one of "
+                         f"{'/'.join(repr(a) for a in BACKEND_TIERS)}")
 
 
 def resolve_backend(backend: Optional[str] = None,
@@ -106,18 +86,14 @@ def resolve_backend(backend: Optional[str] = None,
     """Resolve a backend request to the backend that will run.
 
     ``None`` reads ``env`` (default ``"numpy"``, the bitwise/exact reference)
-    and degrades an env-level request down the pallas → jax → numpy tier
-    order when the requested tier is unavailable. An explicit ``"jax"`` /
-    ``"pallas"`` argument raises ``RuntimeError`` instead of degrading.
+    and degrades an env-level ``"jax"`` request to numpy when jax is
+    unavailable. An explicit ``"jax"`` argument raises ``RuntimeError``
+    instead of degrading; an unknown name raises ``ValueError``.
     """
     defaulted = backend is None
     if defaulted:
         backend = os.environ.get(env, "").strip().lower() or "numpy"
     check_backend(backend)
-    if backend == "pallas" and not pallas_available():
-        if not defaulted:
-            raise RuntimeError(_PALLAS_MISSING_MSG)
-        backend = "jax"                       # degrade one tier and re-check
     if backend == "jax" and not jax_available():
         if defaulted:
             return "numpy"
@@ -146,8 +122,8 @@ def dispatch_count(kind: Optional[str] = None) -> int:
 
 def require_jax():
     """Import (jax, jax.numpy, enable_x64), raising the shared message when
-    jax is not installed. The jax and Pallas kernel caches build through
-    this; ``enable_x64()`` is a context manager that turns float64 on."""
+    jax is not installed. The jax kernel caches build through this;
+    ``enable_x64()`` is a context manager that turns float64 on."""
     if not jax_available():
         raise RuntimeError(_JAX_MISSING_MSG)
     import jax

@@ -136,13 +136,14 @@ class ControllerConfig:
 # SLO-aware admission control (§5.4 burst survival)
 # ---------------------------------------------------------------------------
 
-def _admit_mask(times: np.ndarray, budgets: np.ndarray, bs: int, t_in: float,
-                clock: float) -> np.ndarray:
+def _admit_mask_multi(times: np.ndarray, sids: np.ndarray,
+                      bss: Sequence[int], t_ins: Sequence[float],
+                      budgets: np.ndarray, clock: float) -> np.ndarray:
     """Deadline-drop admission over one window's effective arrivals (carried
     pending requests first, then the window's own — exactly the vector the
     managed engine would run). A virtual copy of the engine runs the same
     recurrence over the *admitted* subsequence: ``clock`` is when the device
-    frees up, ``batch`` the forming minibatch's member indices. Whenever the
+    frees up, ``batches[j]`` stream j's forming minibatch. Whenever the
     batch fills, its completion is ``max(clock, ready) + t_in`` — the
     engine's own fold — and the oldest members whose wait already exceeds
     their budget are dropped (deadline-expired work is shed rather than
@@ -158,38 +159,13 @@ def _admit_mask(times: np.ndarray, budgets: np.ndarray, bs: int, t_in: float,
     the virtual queue inside the budget, which is why admitted-request
     satisfaction holds even when the offered load cannot drain. A trailing
     partial batch is admitted untouched — the engine carries it to the next
-    window, where the next admission pass re-judges it as backlog."""
-    times = np.asarray(times, np.float64)
-    n = times.size
-    admit = np.ones(n, bool)
-    if n == 0:
-        return admit
-    budgets = np.asarray(budgets, np.float64)
-    c = float(clock)
-    bs, t_in = int(bs), float(t_in)
-    batch: list[int] = []
-    for i in range(n):
-        batch.append(i)
-        if len(batch) < bs:
-            continue
-        comp = max(c, float(times[i])) + t_in
-        while batch and (comp - float(times[batch[0]])
-                         > float(budgets[batch[0]]) + 1e-12):
-            admit[batch.pop(0)] = False
-        if len(batch) == bs:
-            c = comp
-            batch = []
-    return admit
+    window, where the next admission pass re-judges it as backlog.
 
-
-def _admit_mask_multi(times: np.ndarray, sids: np.ndarray,
-                      bss: Sequence[int], t_ins: Sequence[float],
-                      budgets: np.ndarray, clock: float) -> np.ndarray:
-    """N-stream form of ``_admit_mask``: one shared virtual device clock
-    (every tenant's batches serialize on the accelerator, so congestion in
-    one stream delays all), per-stream forming batches. ``budgets`` is
-    per-*request* (the policy bakes priorities in before calling),
-    ``times``/``sids`` must be time-sorted."""
+    N streams share one virtual device clock (every tenant's batches
+    serialize on the accelerator, so congestion in one stream delays all),
+    each with its own forming batch; one stream is the single-device mask.
+    ``budgets`` is per-*request* (the policy bakes priorities in before
+    calling), ``times``/``sids`` must be time-sorted."""
     times = np.asarray(times, np.float64)
     n = times.size
     admit = np.ones(n, bool)
@@ -277,9 +253,10 @@ class AdmissionPolicy:
     def admit(self, times: np.ndarray, nominal_budget: float, bs: int,
               t_in: float, clock: float) -> np.ndarray:
         """Single-stream admission mask over the effective arrival vector."""
-        buds = np.full(np.asarray(times).shape[0] if np.ndim(times) else 0,
-                       self.headroom * float(nominal_budget))
-        return _admit_mask(times, buds, bs, t_in, clock)
+        n = np.asarray(times).shape[0] if np.ndim(times) else 0
+        return _admit_mask_multi(times, np.zeros(n, np.int64), [bs], [t_in],
+                                 np.full(n, self.headroom
+                                         * float(nominal_budget)), clock)
 
     def admit_multi(self, times: np.ndarray, sids: np.ndarray,
                     bss: Sequence[int], t_ins: Sequence[float],

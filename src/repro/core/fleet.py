@@ -427,6 +427,110 @@ def _fleet_report(rate, device_reports, merged, counts, latency_budget,
                              power_budgets=power_budgets)
 
 
+def _solve_ladder(grid, ts: np.ndarray, ps: np.ndarray, pbud: np.ndarray,
+                  bud: np.ndarray, nominal: np.ndarray, est: np.ndarray,
+                  hi: np.ndarray, live: np.ndarray, backend: str) -> list:
+    """The planning ladder, vectorized over the device axis: every rung is
+    one batched fleet solve over the still-unsolved devices (the host form
+    of the fused program's masked rungs). Returns one ``Solution`` or None
+    per device."""
+    K = est.size
+    sols: list[Optional[P.Solution]] = [None] * K
+    unsolved = np.ones(K, bool)
+
+    def rung(mask, rates_lo, budgets, rate_his):
+        sel = np.flatnonzero(mask)
+        if not sel.size:
+            return
+        probs = [P.InferProblem(float(pbud[d]), float(budgets[d]),
+                                float(rates_lo[d])) for d in sel]
+        res = solve_infer_fleet_batch(probs, rate_his[sel], grid, ts[sel],
+                                      ps[sel], backend=backend)
+        for d, s in zip(sel, res):
+            sols[d] = s
+            unsolved[d] = s is None
+
+    # 1. margin headroom: sustainable up to hi, budget held at est
+    rung(live & (hi > est), est, bud, hi)
+    # 2. dead zone: prefer the high end (see _closed_loop_window)
+    rung(live & (hi > est) & unsolved, hi, bud, hi)
+    # 3. the point plan at the estimate
+    rung(live & unsolved, est, bud, est)
+    # 4. feedback tightened into infeasibility: retry at nominal
+    rung(live & unsolved & (bud < nominal), est, nominal, est)
+    return sols
+
+
+def _fused_carry_in(state: FleetControllerState, cfg: ControllerConfig,
+                    dtr: Sequence[ArrivalTrace], t0: float):
+    """The engine-side carry-in, flattened for the fused program: device
+    d's effective arrival vector [carried pending, dispatched arrivals] and
+    its pre-switch clock max(carried clock, t0) — ``window_carry_in`` minus
+    the switch cost, which the program charges in-line from the previous
+    mode. Returns ``(eff, n_carry, clock0)``."""
+    K = len(dtr)
+    eff: list[np.ndarray] = []
+    n_carry = np.zeros(K, np.int64)
+    clock0 = np.full(K, float(t0))
+    for d in range(K):
+        st = state.devices[d]
+        if cfg.carry_backlog and st.carry is not None:
+            pend = np.asarray(st.carry.pending, np.float64)
+            clock0[d] = max(float(st.carry.clock), float(t0))
+            n_carry[d] = pend.size
+            eff.append(np.concatenate([pend, dtr[d].times])
+                       if pend.size else dtr[d].times)
+        else:
+            eff.append(dtr[d].times)
+    return eff, n_carry, clock0
+
+
+def _fused_results(res: dict, grid, state: FleetControllerState,
+                   dtr: Sequence[ArrivalTrace], n_carry: np.ndarray,
+                   trims: bool, prev_mode: np.ndarray):
+    """The fused window's plans and ``ExecutionReport``s, rebuilt from the
+    fetched arrays with the same float ops ``simulate_batch`` applies.
+    Commits each solved device's mode (``prev_mode`` is updated in place).
+    Returns ``(sols, switches, reps)``, one entry per device (None where
+    nothing was solved)."""
+    K = len(dtr)
+    sols: list = [None] * K
+    switches = np.zeros(K)
+    reps: list = [None] * K
+    for d in range(K):
+        if not res["solved"][d]:
+            continue
+        sel = int(res["sel"][d])
+        sol = P.Solution(pm=grid.modes[sel], bs=int(grid.bs[sel]),
+                         time=float(res["lam"][d]),
+                         power=float(res["power"][d]))
+        sols[d] = sol
+        switches[d] = state.mode_switch(d, sol.pm)      # == res["switch"]
+        bs = sol.bs
+        n_adm = int(res["n_adm"][d])
+        nb = int(res["n_batches"][d])
+        ctv = np.asarray(res["adm_times"][d][:n_adm], np.float64)
+        if trims and res["n_rej"][d]:
+            # rebuilt exactly as _admit_fleet_device does: the admitted
+            # window arrivals follow the admitted carry prefix
+            nca = int(n_carry[d]) - int(res["n_carry_rej"][d])
+            run_tr = ArrivalTrace(ctv[nca:].copy(), dtr[d].duration,
+                                  dtr[d].kind)
+        else:
+            run_tr = dtr[d]
+        power = float(res["power"][d])
+        reps[d] = ExecutionReport(
+            "managed",
+            np.asarray(res["latencies"][d][:nb * bs], np.float64).copy(),
+            0, run_tr.duration, power, run_tr,
+            queue_state=QueueState(ctv[nb * bs:].copy(),
+                                   float(res["clock_out"][d])),
+            attributed_power=power if nb else 0.0)
+        prev_mode[d] = int(res["mode_id"][d])
+    _presort_reports([r for r in reps if r is not None])
+    return sols, switches, reps
+
+
 def serve_fleet(w: WorkloadProfile, power_budget: float,
                 latency_budget: float, rates: Sequence[float],
                 spec: FleetSpec, window_duration: float = 30.0,
@@ -437,43 +541,45 @@ def serve_fleet(w: WorkloadProfile, power_budget: float,
                 fused: Optional[bool] = None,
                 ) -> list[FleetWindowReport]:
     """Serve a dynamic aggregate trace on a K-device fleet, stepping all K
-    per-device closed-loop windows as one batched program per window: one
-    dispatch pass (deferred re-offers re-entering first), one batched solve
-    per ladder rung (per-device water-filled power budgets when the spec
-    sets a fleet cap), one admission pass over the solved lanes, one
-    ``simulate_batch`` over the admitted traces. Bitwise-identical on NumPy
-    to ``serve_fleet_sequential`` (the K independent scalar loops).
+    per-device closed-loop windows as one batched program per window. Each
+    window runs three stages:
 
-    ``fused=True`` (jax/pallas backends only) runs each window through the
-    fused solve+simulate program instead — ONE compiled launch per window
-    (``core.fused_window``), tolerance-identical to this per-rung path.
-    The default (``None``/False) keeps the unfused loop, so the NumPy
-    reference path stays byte-identical."""
+     * **prepare** — migration, one dispatch pass (deferred re-offers
+       re-entering first), the announced and estimated rates, burst
+       quantiles, per-device (water-filled) power and latency budgets;
+     * **run** — per rung: one batched solve per ladder rung, one host
+       admission pass over the solved lanes, one ``simulate_batch`` over
+       the admitted traces; or, with ``fused=True`` (jax backend only),
+       ONE compiled launch per window (``core.fused_window``) with the
+       plans and reports rebuilt from the fetched arrays;
+     * **settle** — re-deferral, the shed/defer split of rejections,
+       goodput, controller feedback and the window's reports.
+
+    The per-rung form is bitwise-identical on NumPy to
+    ``serve_fleet_sequential`` (the K independent scalar loops); the fused
+    form is tolerance-identical to the per-rung jax form. The default
+    (``None``/False) runs per rung, so the NumPy reference path stays
+    byte-identical."""
     cfg = controller if controller is not None else ControllerConfig()
     _check_fleet_features(spec, cfg)
     adm = cfg.admission_policy()
+    eng_backend = resolve_backend(backend)
     if fused:
-        eng = resolve_backend(backend)
-        if eng == "numpy":
+        if eng_backend == "numpy":
             raise ValueError(
                 "the fused fleet window is a jax program; request "
-                "backend='jax' (or 'pallas'), or leave fused off for the "
-                "NumPy reference path")
+                "backend='jax', or leave fused off for the NumPy "
+                "reference path")
         if adm.mode == "degrade-bs":
             raise ValueError(
                 "admission mode 'degrade-bs' re-plans on the host between "
                 "solve and simulate (problem.solve_infer_capacity over the "
                 "device dict); serve it unfused — the fused window supports "
                 "admission none/shed/defer")
-        return _serve_fleet_fused(w, power_budget, latency_budget, rates,
-                                  spec, window_duration, arrivals, seed,
-                                  cfg, adm, space)
     K = spec.n_devices
     devs, ts, ps, wts, shares = _fleet_scales(spec)
     grid = materialize(DeviceModel(), w, space or PowerModeSpace(),
                        P.INFER_BATCH_SIZES)
-    eng_backend = resolve_backend(backend)
-    sol_backend = "numpy" if eng_backend == "numpy" else "jax"
     state = FleetControllerState(cfg, K)
     obs_cache: dict[int, dict] = {}     # degrade-bs only: per-device grids
     base_obs: list = []                 # the shared base dict, converted at
@@ -488,164 +594,10 @@ def serve_fleet(w: WorkloadProfile, power_budget: float,
         return obs_cache[d]
 
     prev_keys: list = [None] * K
-    prev_attr = np.full(K, float(power_budget))
-    out: list[FleetWindowReport] = []
-    from repro.core.scheduler import WindowReport
-    for i, rate in enumerate(rates):
-        t0 = i * window_duration
-        agg = _window_trace(float(rate), i, window_duration, arrivals, seed)
-        n_mig = _migrate_backlog(state.devices, wts, t0) \
-            if spec.migrate_backlog else 0
-        n_def = state.pop_fleet_deferred() if adm.active else 0
-        carried = _backlog_counts(state.devices, cfg)
-        counts0 = carried if spec.dispatch == "least-backlog" else None
-        merged, dtr, own_dtr, def_counts, counts = _dispatch_fleet_window(
-            agg, n_def, t0, wts, counts0, K)
-        announced = float(rate) * shares
-        pbud = _fleet_power_budgets(spec, power_budget, prev_attr, K)
-        # the PR-5 ladder, vectorized over the device axis: every rung is
-        # one batched fleet solve over the still-unsolved devices
-        hi = state.plan_rates(announced, t0, window_duration)
-        est = state.plan_rates(announced, t0, window_duration,
-                               margin=1.0, pressure=False)
-        if cfg.burst_quantile > 0.0:
-            hi = np.maximum(hi, [P.burst_rate(e, window_duration,
-                                              cfg.burst_quantile)
-                                 for e in est])
-        bud = state.plan_budgets([latency_budget] * K)
-        sols: list[Optional[P.Solution]] = [None] * K
-        live = est > 0.0            # a zero estimate has no rate to plan at
-        unsolved = np.ones(K, bool)
-
-        def rung(mask, rates_lo, budgets, rate_his):
-            sel = np.flatnonzero(mask)
-            if not sel.size:
-                return
-            probs = [P.InferProblem(float(pbud[d]), float(budgets[d]),
-                                    float(rates_lo[d])) for d in sel]
-            res = solve_infer_fleet_batch(probs, rate_his[sel], grid,
-                                          ts[sel], ps[sel],
-                                          backend=sol_backend)
-            for d, s in zip(sel, res):
-                sols[d] = s
-                unsolved[d] = s is None
-
-        # 1. margin headroom: sustainable up to hi, budget held at est
-        rung(live & (hi > est), est, bud, hi)
-        # 2. dead zone: prefer the high end (see _closed_loop_window)
-        rung(live & (hi > est) & unsolved, hi, bud, hi)
-        # 3. the point plan at the estimate
-        rung(live & unsolved, est, bud, est)
-        # 4. feedback tightened into infeasibility: retry at nominal
-        nominal = np.full(K, float(latency_budget))
-        rung(live & unsolved & (bud < nominal), est, nominal, est)
-        lanes = []              # (device, sol, switch_s, run_trace, carry)
-        shed_d = np.zeros(K, np.int64)
-        def_out_d = np.zeros(K, np.int64)
-        for d in range(K):
-            sol = sols[d]
-            if sol is not None and adm.mode == "degrade-bs":
-                sol = _degrade_fleet_plan(
-                    sol, float(est[d]), int(carried[d] + def_counts[d]),
-                    window_duration, float(pbud[d]), device_obs(d))
-                sols[d] = sol
-            if sol is None:
-                if def_counts[d]:
-                    # nothing serves here: re-defer this device's re-offers
-                    shed_d[d] += state.push_fleet_deferred(
-                        int(def_counts[d]))
-                state.observe_unserved(d, own_dtr[d], window_duration)
-                continue
-            switch_s = state.mode_switch(d, sol.pm)
-            carry_in = state.window_carry_in(d, t0, switch_s)
-            run_trace, run_carry = dtr[d], carry_in
-            if adm.trims:
-                t_in = devs[d].time_power(w, sol.pm, sol.bs)[0]
-                run_trace, run_carry, n_rej = _admit_fleet_device(
-                    adm, latency_budget, sol, t_in, carry_in, dtr[d])
-                if n_rej:
-                    if adm.mode == "defer":
-                        dropped = state.push_fleet_deferred(n_rej)
-                        def_out_d[d] = n_rej - dropped
-                        shed_d[d] = dropped
-                    else:
-                        shed_d[d] = n_rej
-            lanes.append((d, sol, switch_s, run_trace, run_carry))
-        reps = simulate_batch(
-            DeviceModel(), None, w,
-            [sol.pm for _, sol, _, _, _ in lanes],
-            [sol.bs for _, sol, _, _, _ in lanes],
-            [rt for _, _, _, rt, _ in lanes],
-            tau_caps=[sol.tau_tr for _, sol, _, _, _ in lanes],
-            backend=eng_backend,
-            carry_ins=[rc for _, _, _, _, rc in lanes],
-            devices=[devs[d] for d, _, _, _, _ in lanes])
-        device_reports: list = [None] * K
-        for (d, sol, switch_s, _, _), rep in zip(lanes, reps):
-            offered = len(own_dtr[d])
-            gp = _goodput(rep, latency_budget, offered)
-            rep.goodput = gp
-            rep.shed_requests = int(shed_d[d])
-            rep.deferred_requests = int(def_out_d[d])
-            state.observe(d, own_dtr[d], rep, latency_budget,
-                          window_duration, rep.queue_state)
-            key = (sol.pm, sol.bs, sol.tau_tr)
-            device_reports[d] = WindowReport(
-                float(announced[d]), sol, rep,
-                estimated_rate=float(est[d]),
-                replanned=key != prev_keys[d], mode_switch_s=switch_s,
-                carried_requests=int(carried[d]),
-                shed_requests=int(shed_d[d]),
-                deferred_requests=int(def_out_d[d]), goodput=gp,
-                offered_requests=offered)
-            prev_keys[d] = key
-        for d in range(K):
-            if device_reports[d] is None:
-                offered = len(own_dtr[d])
-                device_reports[d] = WindowReport(
-                    float(announced[d]), None, None,
-                    estimated_rate=float(est[d]),
-                    carried_requests=int(carried[d]),
-                    shed_requests=int(shed_d[d]),
-                    goodput=0.0 if offered else 1.0,
-                    offered_requests=offered)
-        out.append(_fleet_report(
-            rate, device_reports, merged, counts, latency_budget,
-            offered=len(agg), shed=int(shed_d.sum()),
-            deferred=int(def_out_d.sum()), migrated=n_mig,
-            power_budgets=pbud.copy()
-            if spec.fleet_power_budget is not None else None))
-        prev_attr = _attributed_by_device(device_reports)
-    return out
-
-
-def _serve_fleet_fused(w: WorkloadProfile, power_budget: float,
-                       latency_budget: float, rates: Sequence[float],
-                       spec: FleetSpec, window_duration: float,
-                       arrivals: str, seed: int, cfg: ControllerConfig,
-                       adm: AdmissionPolicy,
-                       space: Optional[PowerModeSpace],
-                       ) -> list[FleetWindowReport]:
-    """The fused driver behind ``serve_fleet(fused=True)``: identical
-    host-side bookkeeping (dispatch, deferral, migration, water-filling,
-    controller states) to the unfused loop, but the per-window plan ladder,
-    admission recurrence, and engine run as ONE compiled launch
-    (``core.fused_window.fused_fleet_window``) instead of up to four solver
-    rungs + a host admission pass + an engine launch. Reports are
-    reconstructed from the fetched arrays with the same float ops
-    ``simulate_batch`` would apply, so results match the unfused jax path
-    within the associative-scan tolerance (the padded tree shape is the
-    only difference) and the unfused NumPy reference within the ladder's
-    documented jax tolerance."""
-    K = spec.n_devices
-    devs, ts, ps, wts, shares = _fleet_scales(spec)
-    grid = materialize(DeviceModel(), w, space or PowerModeSpace(),
-                       P.INFER_BATCH_SIZES)
-    state = FleetControllerState(cfg, K)
-    prev_keys: list = [None] * K
-    prev_mode = np.full(K, -1, np.int32)    # committed mode ids; -1 = none
+    prev_mode = np.full(K, -1, np.int32)    # fused: committed mode ids
     prev_attr = np.full(K, float(power_budget))
     adm_budget = adm.headroom * float(latency_budget)
+    nominal = np.full(K, float(latency_budget))
     out: list[FleetWindowReport] = []
     from repro.core.scheduler import WindowReport
     for i, rate in enumerate(rates):
@@ -671,87 +623,67 @@ def _serve_fleet_fused(w: WorkloadProfile, power_budget: float,
                                                       cfg.burst_quantile)
                                          for e in est])
                 bud = state.plan_budgets([latency_budget] * K)
-                nominal = np.full(K, float(latency_budget))
-                live = est > 0.0
-                # the engine-side carry-in, flattened: device d's effective
-                # arrival vector [carried pending, dispatched arrivals] and
-                # its pre-switch clock max(carried clock, t0) —
-                # window_carry_in minus the switch cost, which the program
-                # charges in-line from prev_mode
-                eff: list[np.ndarray] = []
-                n_carry = np.zeros(K, np.int64)
-                clock0 = np.full(K, float(t0))
+                live = est > 0.0    # a zero estimate has no rate to plan at
+                if fused:
+                    eff, n_carry, clock0 = _fused_carry_in(state, cfg, dtr,
+                                                           t0)
+            if fused:
+                res = fused_fleet_window(grid, ts, ps, pbud, bud, nominal,
+                                         est, hi, live, prev_mode, eff,
+                                         n_carry, clock0,
+                                         float(cfg.mode_switch_s),
+                                         adm_budget, adm.trims)
+            else:
+                sols = _solve_ladder(grid, ts, ps, pbud, bud, nominal, est,
+                                     hi, live, eng_backend)
+                switches = np.zeros(K)
+                n_rej = np.zeros(K, np.int64)
+                lanes = []              # (device, sol, run_trace, carry)
                 for d in range(K):
-                    st = state.devices[d]
-                    if cfg.carry_backlog and st.carry is not None:
-                        pend = np.asarray(st.carry.pending, np.float64)
-                        clock0[d] = max(float(st.carry.clock), float(t0))
-                        n_carry[d] = pend.size
-                        eff.append(np.concatenate([pend, dtr[d].times])
-                                   if pend.size else dtr[d].times)
-                    else:
-                        eff.append(dtr[d].times)
-            res = fused_fleet_window(grid, ts, ps, pbud, bud, nominal, est, hi,
-                                     live, prev_mode, eff, n_carry, clock0,
-                                     float(cfg.mode_switch_s), adm_budget,
-                                     adm.trims)
+                    sol = sols[d]
+                    if sol is not None and adm.mode == "degrade-bs":
+                        sol = sols[d] = _degrade_fleet_plan(
+                            sol, float(est[d]),
+                            int(carried[d] + def_counts[d]), window_duration,
+                            float(pbud[d]), device_obs(d))
+                    if sol is None:
+                        continue
+                    switch_s = switches[d] = state.mode_switch(d, sol.pm)
+                    carry_in = state.window_carry_in(d, t0, switch_s)
+                    run_trace, run_carry = dtr[d], carry_in
+                    if adm.trims:
+                        t_in = devs[d].time_power(w, sol.pm, sol.bs)[0]
+                        run_trace, run_carry, n_rej[d] = _admit_fleet_device(
+                            adm, latency_budget, sol, t_in, carry_in, dtr[d])
+                    lanes.append((d, sol, run_trace, run_carry))
+                reps: list = [None] * K
+                for (d, _, _, _), rep in zip(lanes, simulate_batch(
+                        DeviceModel(), None, w,
+                        [sol.pm for _, sol, _, _ in lanes],
+                        [sol.bs for _, sol, _, _ in lanes],
+                        [rt for _, _, rt, _ in lanes],
+                        tau_caps=[sol.tau_tr for _, sol, _, _ in lanes],
+                        backend=eng_backend,
+                        carry_ins=[rc for _, _, _, rc in lanes],
+                        devices=[devs[d] for d, _, _, _ in lanes])):
+                    reps[d] = rep
             with obs.span("fleet.report"):
+                if fused:
+                    sols, switches, reps = _fused_results(
+                        res, grid, state, dtr, n_carry, adm.trims, prev_mode)
+                    n_rej = res["n_rej"]
                 shed_d = np.zeros(K, np.int64)
                 def_out_d = np.zeros(K, np.int64)
-                sols: list = [None] * K
-                switches = np.zeros(K)
-                reps: list = [None] * K
+                device_reports: list = [None] * K
                 for d in range(K):
-                    if not res["solved"][d]:
+                    sol, rep = sols[d], reps[d]
+                    offered = len(own_dtr[d])
+                    if sol is None:
                         if def_counts[d]:
+                            # nothing serves here: re-defer its re-offers
                             shed_d[d] += state.push_fleet_deferred(
                                 int(def_counts[d]))
                         state.observe_unserved(d, own_dtr[d], window_duration)
-                        continue
-                    sel = int(res["sel"][d])
-                    sol = P.Solution(pm=grid.modes[sel], bs=int(grid.bs[sel]),
-                                     time=float(res["lam"][d]),
-                                     power=float(res["power"][d]))
-                    sols[d] = sol
-                    # == res["switch"]
-                    switches[d] = state.mode_switch(d, sol.pm)
-                    n_rej = int(res["n_rej"][d])
-                    if n_rej:
-                        if adm.mode == "defer":
-                            dropped = state.push_fleet_deferred(n_rej)
-                            def_out_d[d] = n_rej - dropped
-                            shed_d[d] = dropped
-                        else:
-                            shed_d[d] = n_rej
-                    bs = sol.bs
-                    n_adm = int(res["n_adm"][d])
-                    nb = int(res["n_batches"][d])
-                    ctv = np.asarray(res["adm_times"][d][:n_adm], np.float64)
-                    if adm.trims and n_rej:
-                        # rebuilt exactly as _admit_fleet_device does: the
-                        # admitted window arrivals follow the admitted carry
-                        # prefix
-                        nca = int(n_carry[d]) - int(res["n_carry_rej"][d])
-                        run_tr = ArrivalTrace(ctv[nca:].copy(),
-                                              dtr[d].duration, dtr[d].kind)
-                    else:
-                        run_tr = dtr[d]
-                    power = float(res["power"][d])
-                    reps[d] = ExecutionReport(
-                        "managed",
-                        np.asarray(res["latencies"][d][:nb * bs],
-                                   np.float64).copy(),
-                        0, run_tr.duration, power, run_tr,
-                        queue_state=QueueState(ctv[nb * bs:].copy(),
-                                               float(res["clock_out"][d])),
-                        attributed_power=power if nb else 0.0)
-                    prev_mode[d] = int(res["mode_id"][d])
-                _presort_reports([r for r in reps if r is not None])
-                device_reports: list = [None] * K
-                for d in range(K):
-                    rep = reps[d]
-                    offered = len(own_dtr[d])
-                    if rep is None:
                         device_reports[d] = WindowReport(
                             float(announced[d]), None, None,
                             estimated_rate=float(est[d]),
@@ -760,7 +692,14 @@ def _serve_fleet_fused(w: WorkloadProfile, power_budget: float,
                             goodput=0.0 if offered else 1.0,
                             offered_requests=offered)
                         continue
-                    sol = sols[d]
+                    rejected = int(n_rej[d])
+                    if rejected:
+                        if adm.mode == "defer":
+                            dropped = state.push_fleet_deferred(rejected)
+                            def_out_d[d] = rejected - dropped
+                            shed_d[d] = dropped
+                        else:
+                            shed_d[d] = rejected
                     gp = _goodput(rep, latency_budget, offered)
                     rep.goodput = gp
                     rep.shed_requests = int(shed_d[d])

@@ -20,18 +20,20 @@ per (K-bucket, event-bucket) shape:
    per-rung path bitwise; computing a rung for an already-solved device is
    free parallel work whose result the gate discards.
  * **in-program admission** — the exact deadline-drop recurrence
-   (``controller._admit_mask``) expressed as a ``lax.scan`` over arrivals
-   with a ``max_bs`` ring buffer of forming-batch members and a bounded
-   ``while_loop`` for the drop-from-front rule (total drops across a window
-   are <= the arrival count). Rejected requests are compacted out by a
-   stable sort against +inf — admitted times are a nondecreasing
-   subsequence, so the sort yields exactly the trimmed vector the unfused
-   path would rebuild on the host.
+   (``controller._admit_mask_multi`` at one stream) expressed as a
+   ``lax.scan`` over arrivals with a ``max_bs`` ring buffer of
+   forming-batch members and a bounded ``while_loop`` for the
+   drop-from-front rule (total drops across a window are <= the arrival
+   count). Rejected requests are compacted out by a stable sort against
+   +inf — admitted times are a nondecreasing subsequence, so the sort
+   yields exactly the trimmed vector the unfused path would rebuild on the
+   host.
  * **fused execution** — the selected ``(bs, t_in)`` lanes feed straight
    into the max-plus associative scan (the PR-4 engine kernel, same
-   combine), with batch-ready events gathered by traced-``bs`` indexing
-   instead of host-side strided slicing. Mode-switch costs are charged
-   in-program from the previous window's committed mode ids.
+   ``simulate._maxplus_combine``), with batch-ready events gathered by
+   traced-``bs`` indexing instead of host-side strided slicing. Mode-switch
+   costs are charged in-program from the previous window's committed mode
+   ids.
 
 Solve -> admit -> simulate never crosses the host boundary: one launch per
 window (``backend.dispatch_count("fused")`` tracks it; the legacy path pays
@@ -59,12 +61,12 @@ import numpy as np
 from repro import obs
 from repro.core.backend import record_dispatch, require_jax, with_program
 from repro.core.grid_eval import ObservationGrid, device_grid_arrays
-from repro.core.simulate import _pow2
+from repro.core.simulate import _maxplus_combine, _pow2
 
 # one compiled program per (trims, max_bs) variant x jit shape bucket
 _FUSED_CACHE: dict = {}
 
-# the admission slack, exactly controller._admit_mask's
+# the admission slack, exactly controller._admit_mask_multi's
 _ADMIT_EPS = 1e-12
 
 
@@ -105,16 +107,12 @@ def _fused_kernel(trims: bool, max_bs: int):
         return _FUSED_CACHE[key]
     jax, jnp, enable_x64 = require_jax()
 
-    def combine(left, right):           # the max-plus affine composition,
-        a_l, b_l = left                 # exactly simulate._jax_engine's
-        a_r, b_r = right
-        return a_l + a_r, jnp.maximum(b_l + a_r, b_r)
-
     def admit_lane(tv, n, bs, t_in, budget, clock):
-        # controller._admit_mask as a scan: ring buffer of forming-batch
-        # member indices (members are a window [h, h+m) mod max_bs), one
-        # bounded drop loop per filled batch. Same float ops, same 1e-12
-        # slack, so the mask matches the host recurrence bitwise.
+        # controller._admit_mask_multi at one stream, as a scan: ring
+        # buffer of forming-batch member indices (members are a window
+        # [h, h+m) mod max_bs), one bounded drop loop per filled batch.
+        # Same float ops, same 1e-12 slack, so the mask matches the host
+        # recurrence bitwise.
         T = tv.shape[0]
 
         def step(carry, i):
@@ -249,7 +247,8 @@ def _fused_kernel(trims: bool, max_bs: int):
                 jnp.take_along_axis(ctimes, jnp.clip(last, 0, T - 1), axis=1),
                 inf)
             ex = jnp.where(validb, t_in[:, None], 0.0)
-            a, b = jax.lax.associative_scan(combine, (ex, ready + ex), axis=1)
+            a, b = jax.lax.associative_scan(_maxplus_combine, (ex, ready + ex),
+                                            axis=1)
             comp = jnp.maximum(clock_in[:, None] + a, b)
             bidx = jnp.clip(iota[None, :] // bs_c[:, None], 0, T - 1)
             served = iota[None, :] < (nb * bs_c)[:, None]

@@ -32,10 +32,8 @@ against randomized grids and the full 441 x 5 sweep. The jax backend runs
 the same reductions under ``enable_x64`` (masked argmin/argmax are
 reassociation-free, so it stays bitwise-equal too — unlike the execution
 engine's scan, see ``docs/exactness.md``). Backend names are validated by
-the shared ``core.backend`` plumbing, also used by ``core.simulate`` — the
-solvers accept only the "numpy"/"jax" tiers (the "pallas" tier is an
-execution-engine backend; there is no Pallas solver kernel, so asking for
-it here is a ``ValueError`` rather than a silent NumPy fallback). Ragged
+the shared ``core.backend`` plumbing, also used by ``core.simulate`` (an
+unknown name is a ``ValueError`` rather than a silent NumPy fallback). Ragged
 final problem chunks are padded to power-of-two row buckets before hitting
 the jit kernels, so sweeping many batch sizes reuses a handful of
 compilations — ``solver_trace_count()`` exposes the retrace counter.
@@ -311,7 +309,7 @@ def solve_train_batch(problems: Sequence[P.TrainProblem],
     """Batched ``problem.solve_train``: argmax theta_tr s.t. p <= p-hat for
     every problem at once. Returns one Optional[Solution] per problem,
     bitwise identical to the scalar loop."""
-    check_backend(backend, ("numpy", "jax"))
+    check_backend(backend)
     grid = as_train_grid(obs)
     out: list[Optional[P.Solution]] = [None] * len(problems)
     if not len(grid) or not len(problems):
@@ -347,7 +345,7 @@ def solve_infer_batch(problems: Sequence[P.InferProblem],
                       backend: str = "numpy") -> list[Optional[P.Solution]]:
     """Batched ``problem.solve_infer``: argmin peak latency s.t. power,
     latency, and sustainability constraints, over a batch of problems."""
-    check_backend(backend, ("numpy", "jax"))
+    check_backend(backend)
     grid = as_infer_grid(obs)
     out: list[Optional[P.Solution]] = [None] * len(problems)
     if not len(grid) or not len(problems):
@@ -415,7 +413,7 @@ def solve_infer_fleet_batch(problems: Sequence[P.InferProblem],
     water-fills one cap into per-device budgets and each device's grant
     lands in its problem row. The fleet planner solves all K per-device
     windows with one call per window."""
-    check_backend(backend, ("numpy", "jax"))
+    check_backend(backend)
     grid = as_infer_grid(obs)
     out: list[Optional[P.Solution]] = [None] * len(problems)
     if not len(grid) or not len(problems):
@@ -484,7 +482,7 @@ def solve_concurrent_batch(problems: Sequence[P.ConcurrentProblem],
     """Batched ``problem.solve_concurrent``: lexicographic argmax of
     (training throughput, -peak latency) under the interleaving feasibility
     mask, for every problem at once."""
-    check_backend(backend, ("numpy", "jax"))
+    check_backend(backend)
     tg = as_train_grid(train_obs)
     ig = as_infer_grid(infer_obs)
     out: list[Optional[P.Solution]] = [None] * len(problems)
@@ -678,7 +676,7 @@ def solve_multi_tenant_batch(problems: Sequence["P.MultiTenantProblem"],
     """Batched ``problem.solve_multi_tenant``: every problem must share the
     stream count, train flag, and per-stream batch-size restrictions; rates,
     latency budgets, and power budgets vary per problem."""
-    check_backend(backend, ("numpy", "jax"))
+    check_backend(backend)
     out: list[Optional[P.MultiTenantSolution]] = [None] * len(problems)
     if not len(problems):
         return out

@@ -55,16 +55,10 @@ Backends (contract; see ``docs/exactness.md`` for the full ladder):
    *tolerance-checked* against NumPy (|Δlatency| ≲ K·eps·T, enforced at
    atol=1e-8 s / rtol=1e-9 by ``tests/test_simulate.py``; train-minibatch
    counts may differ only on quotient-boundary cases), **not** bitwise.
- * ``backend="pallas"`` — the same contract served by the hand-written
-   Pallas kernels (``repro.kernels.fulcrum``): a lane-blocked Hillis-Steele
-   max-plus scan fused with the slack-fill count, and the report builder's
-   per-lane padded sort as a bitonic network. Same tolerance rung as jax
-   (the sort itself is a pure permutation — checked for equality);
-   ``interpret=True`` off-TPU, so the kernels run on CPU CI.
 
 Backend selection follows ``core.backend.resolve_backend``: ``None`` defers
-to ``FULCRUM_ENGINE_BACKEND`` and degrades pallas → jax → numpy when a tier
-is unavailable. Reports from the batched paths are built by one vectorized
+to ``FULCRUM_ENGINE_BACKEND`` and degrades jax → numpy when jax is
+unavailable. Reports from the batched paths are built by one vectorized
 report builder: a chunked padded sort fills every lane's quantile /
 violation-rate cache.
 
@@ -72,8 +66,8 @@ Lane scaling (10⁴–10⁵ lanes): the accelerator paths never materialize one
 giant padded matrix — lanes are dispatched in ``_LANE_CHUNK``-sized chunks
 padded to power-of-two lane buckets and one *global* power-of-two event
 count, so every chunk of a sweep (and of the next sweep) hits the same
-compiled program. The compiled kernels live in module-level caches keyed by
-backend (jit itself caches per padded shape); ``engine_trace_count()``
+compiled program. The compiled kernel lives in a module-level cache (jit
+itself caches per padded shape); ``engine_trace_count()``
 exposes a retrace counter so tests can pin the no-retrace contract. Scan
 input buffers are donated (``donate_argnums``) — they are per-call padded
 copies, never reused host-side.
@@ -477,11 +471,10 @@ def _latencies(completions: np.ndarray, times: np.ndarray,
 _SORT_CHUNK_ELEMS = 4 << 20
 
 
-def _sort_lane_chunk(lats: list[np.ndarray], reports, backend: str) -> None:
+def _sort_lane_chunk(lats: list[np.ndarray], reports) -> None:
     """Sort one chunk of lanes through a padded (lane, request) matrix.
     Sorting permutes values — the sorted arrays are identical float64
-    multisets whichever backend sorts, so the NumPy path stays bitwise and
-    the Pallas bitonic kernel is interchangeable (equality-checked)."""
+    multisets to one ``np.sort`` per report, so the cache stays bitwise."""
     R = max(a.size for a in lats)
     total = sum(a.size for a in lats)
     if len(lats) * R > 4 * total:      # highly ragged: padding would cost
@@ -491,26 +484,20 @@ def _sort_lane_chunk(lats: list[np.ndarray], reports, backend: str) -> None:
     mat = np.full((len(lats), R), np.inf)
     for i, a in enumerate(lats):
         mat[i, :a.size] = a
-    if backend == "pallas":
-        mat = np.asarray(_pallas_lane_sort()(mat))
-    else:
-        mat.sort(axis=1)
+    mat.sort(axis=1)
     for i, (r, a) in enumerate(zip(reports, lats)):
         # copy: a view would pin the whole padded matrix per report
         r._sorted = mat[i, :a.size].copy()
 
 
-def _presort_reports(reports: Sequence[ExecutionReport],
-                     backend: str = "numpy") -> None:
+def _presort_reports(reports: Sequence[ExecutionReport]) -> None:
     """Batched report builder: fill every report's quantile/violation cache
     with chunked vectorized sorts over padded (lane, request) matrices, so
     per-lane statistics of a batch are computed vectorized rather than one
     Python-level sort per report. +inf padding keeps each lane's real
     latencies as the leading prefix after the sort; chunks are cut so no
     padded matrix exceeds ``_SORT_CHUNK_ELEMS`` elements (each chunk pads to
-    its own max length, so one long lane cannot inflate the whole batch).
-    ``backend="pallas"`` routes the chunk sorts through the bitonic lane-sort
-    kernel — identical sorted values, NumPy remains the bitwise reference."""
+    its own max length, so one long lane cannot inflate the whole batch)."""
     lats = [np.asarray(r.latencies, np.float64) for r in reports]
     if max((a.size for a in lats), default=0) == 0:
         for r in reports:
@@ -524,7 +511,7 @@ def _presort_reports(reports: Sequence[ExecutionReport],
             if (j + 1 - i) * width > _SORT_CHUNK_ELEMS:
                 break
             j += 1
-        _sort_lane_chunk(lats[i:j], reports[i:j], backend)
+        _sort_lane_chunk(lats[i:j], reports[i:j])
         i = j
 
 
@@ -653,20 +640,16 @@ ENGINES: dict[str, Callable[..., ExecutionReport]] = {
 
 
 # ---------------------------------------------------------------------------
-# jax / pallas backends: the managed kernel as a vmapped max-plus scan.
+# jax backend: the managed kernel as a vmapped max-plus scan.
 # c_k = max(c_{k-1}, ready_k) + e_k is the composition of affine max-plus
 # maps f_k(x) = max(x + a_k, b_k) with a_k = e_k, b_k = ready_k + e_k;
 # (f_r . f_l) keeps that form with (a, b) = (a_l + a_r, max(b_l + a_r, b_r)),
 # so an associative scan over the (a, b) pairs yields every prefix
 # composition, and c_k = prefix_k applied to c_0 = 0 = max(A_k, B_k).
 # Lanes are padded with ready = +inf, exec = 0 (absorbing for both ops).
-# The "jax" tier uses jax.lax.associative_scan; the "pallas" tier the
-# hand-written lane-blocked kernel (repro.kernels.fulcrum.maxplus_scan).
 # ---------------------------------------------------------------------------
 
-# compiled scan runners, keyed by backend tier ("managed" = the jax tier's
-# historical key, kept so tests/monkeypatches keep working; "pallas" = the
-# Pallas kernel wrapper; "lane_sort" = the report builder's bitonic sort)
+# the compiled scan runner, under "managed" (tests monkeypatch that key)
 _JAX_ENGINE_CACHE: dict = {}
 
 # lanes dispatched per compiled call: bounds the padded chunk matrix to
@@ -675,8 +658,8 @@ _LANE_CHUNK = 8192
 
 
 def engine_trace_count() -> int:
-    """Number of scan-kernel (re)traces since import, across backends: it
-    lets tests pin the shape-bucketing no-retrace contract."""
+    """Number of scan-kernel (re)traces since import: it lets tests pin the
+    shape-bucketing no-retrace contract."""
     return obs.counted("trace.engine")
 
 
@@ -690,18 +673,23 @@ def _quiet_donation():
         yield
 
 
+def _maxplus_combine(left, right):
+    """Compose two max-plus affine maps given as ``(a, b)`` pairs: the
+    associative operator of the engine's scan (and the fused window's)."""
+    import jax.numpy as jnp
+    a_l, b_l = left
+    a_r, b_r = right
+    return a_l + a_r, jnp.maximum(b_l + a_r, b_r)
+
+
 def _jax_engine() -> Callable:
     if "managed" in _JAX_ENGINE_CACHE:
         return _JAX_ENGINE_CACHE["managed"]
     jax, jnp, enable_x64 = require_jax()
 
-    def combine(left, right):
-        a_l, b_l = left
-        a_r, b_r = right
-        return a_l + a_r, jnp.maximum(b_l + a_r, b_r)
-
     def one_lane(ready, exec_t, t_tr, tau_cap, clock):
-        a, b = jax.lax.associative_scan(combine, (exec_t, ready + exec_t))
+        a, b = jax.lax.associative_scan(_maxplus_combine,
+                                        (exec_t, ready + exec_t))
         # prefix compositions applied to c_0 = clock (the carried window
         # boundary; 0 for a fresh run): c_k = max(clock + A_k, B_k)
         c = jnp.maximum(clock + a, b)
@@ -732,50 +720,6 @@ def _jax_engine() -> Callable:
     return run
 
 
-def _pallas_engine() -> Callable:
-    """The Pallas-tier scan runner: same contract as ``_jax_engine``'s, the
-    arithmetic done by the hand-written lane-blocked kernel. Jitted so the
-    interpret-mode kernel body is traced once per padded shape (and so the
-    retrace counter counts its compilations the same way)."""
-    if "pallas" in _JAX_ENGINE_CACHE:
-        return _JAX_ENGINE_CACHE["pallas"]
-    jax, jnp, enable_x64 = require_jax()
-    from repro.kernels.fulcrum.maxplus_scan import maxplus_scan
-
-    def batch(ready, exec_t, t_tr, tau_cap, clock):
-        obs.count("trace.engine")              # fires at trace time only
-        return maxplus_scan(ready, exec_t, t_tr, tau_cap, clock)
-
-    kernel = jax.jit(batch, donate_argnums=(0, 1))
-
-    def run(ready, exec_t, t_tr, tau_cap, clock):
-        record_dispatch("engine")
-        with enable_x64(), _quiet_donation():
-            c, trained = kernel(jnp.asarray(ready), jnp.asarray(exec_t),
-                                jnp.asarray(t_tr), jnp.asarray(tau_cap),
-                                jnp.asarray(clock))
-        return np.asarray(c), np.asarray(trained)
-
-    _JAX_ENGINE_CACHE["pallas"] = run
-    return run
-
-
-def _pallas_lane_sort() -> Callable:
-    """Jitted wrapper of the bitonic lane-sort kernel (report builder)."""
-    if "lane_sort" in _JAX_ENGINE_CACHE:
-        return _JAX_ENGINE_CACHE["lane_sort"]
-    jax, jnp, enable_x64 = require_jax()
-    from repro.kernels.fulcrum.lane_sort import lane_sort
-    kernel = jax.jit(lane_sort, donate_argnums=(0,))
-
-    def run(mat):
-        with enable_x64(), _quiet_donation():
-            return np.asarray(kernel(jnp.asarray(mat)))
-
-    _JAX_ENGINE_CACHE["lane_sort"] = run
-    return run
-
-
 def _pow2(n: int, floor: int = 8) -> int:
     return max(floor, 1 << max(0, n - 1).bit_length())
 
@@ -800,11 +744,11 @@ def _pad_lanes(readies: Sequence[np.ndarray], execs: Sequence[np.ndarray],
     return ready, exec_t
 
 
-def _run_engine(backend: str, readies: Sequence[np.ndarray],
+def _run_engine(readies: Sequence[np.ndarray],
                 execs: Sequence[np.ndarray], t_trs: np.ndarray,
                 tau_caps: np.ndarray, clocks: np.ndarray,
                 ) -> tuple[list[np.ndarray], np.ndarray]:
-    """Chunked lane dispatch for the accelerator scan tiers.
+    """Chunked lane dispatch for the jax scan.
 
     Lanes run in ``_LANE_CHUNK``-sized chunks so 10^5-lane sweeps never
     materialize one giant padded matrix; every chunk is padded to a
@@ -813,7 +757,7 @@ def _run_engine(backend: str, readies: Sequence[np.ndarray],
     chunks of the next sweep — hit the same compiled program. Padding lanes
     are absorbing (+inf ready, 0 exec, +inf t_tr, clock 0). Returns each
     lane's trimmed completion vector plus the per-lane fill sums."""
-    run = _pallas_engine() if backend == "pallas" else _jax_engine()
+    run = _jax_engine()
     n = len(readies)
     k_pad = _pow2(max((r.size for r in readies), default=0))
     comps: list[np.ndarray] = []
@@ -1037,8 +981,7 @@ def simulate_multi_tenant_batch(
         eff, clock = _carry_stream_traces(traces, ci)
         ready, exec_t, sid = _merge_events(eff, bss, [t for t, _ in tps])
         lanes.append((tps, ttr, ready, exec_t, sid, eff, clock))
-    comps, trained_f = _run_engine(backend,
-                                   [ln[2] for ln in lanes],
+    comps, trained_f = _run_engine([ln[2] for ln in lanes],
                                    [ln[3] for ln in lanes],
                                    np.array([ln[1][0] for ln in lanes]),
                                    _tau_array(caps),
@@ -1070,7 +1013,7 @@ def simulate_multi_tenant_batch(
                                      ArrivalTrace.merge(eff),
                                      queue_state=state,
                                      train_attributed_power=attr[-1]))
-    _presort_reports(flat, backend=backend)
+    _presort_reports(flat)
     return out
 
 
@@ -1167,7 +1110,7 @@ def simulate_batch(device: DeviceModel, w_tr: Optional[WorkloadProfile],
                for (times, _), bs in zip(lane_times, bss)]
     execs = [np.broadcast_to(np.float64(t), r.shape)
              for (t, _), r in zip(tps, readies)]
-    comps, trained_f = _run_engine(backend, readies, execs,
+    comps, trained_f = _run_engine(readies, execs,
                                    np.array([t for t, _ in ttr]),
                                    _tau_array(caps),
                                    np.array([cl for _, cl in lane_times]))
@@ -1186,7 +1129,7 @@ def simulate_batch(device: DeviceModel, w_tr: Optional[WorkloadProfile],
             "managed", _latencies(comp, times, int(bs)), trained,
             tr.duration, power, tr, queue_state=state,
             attributed_power=attr[0]))
-    _presort_reports(reports, backend=backend)
+    _presort_reports(reports)
     return reports
 
 

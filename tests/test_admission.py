@@ -23,8 +23,7 @@ import pytest
 from repro.core import problem as P
 from repro.core import simulate as S
 from repro.core.controller import (AdmissionPolicy, ControllerConfig,
-                                   ControllerState, _admit_mask,
-                                   _admit_mask_multi)
+                                   ControllerState, _admit_mask_multi)
 from repro.core.device_model import DeviceModel, INFER_WORKLOADS
 from repro.core.powermode import PowerModeSpace
 from repro.core.scheduler import Fulcrum
@@ -202,7 +201,8 @@ def test_admit_mask_sheds_stale_carry_first():
     times = np.concatenate([np.zeros(4),                 # stale carry
                             5.0 + np.arange(8) * 0.01])  # fresh, fast
     budgets = np.full(times.size, 0.2)
-    mask = _admit_mask(times, budgets, 4, 0.01, clock=5.0)
+    mask = _admit_mask_multi(times, np.zeros(times.size, np.int64), [4],
+                             [0.01], budgets, clock=5.0)
     assert not mask[:4].any()
     assert mask[4:].all()
 
@@ -210,13 +210,14 @@ def test_admit_mask_sheds_stale_carry_first():
 def test_admit_mask_trailing_partial_batch_admitted():
     # 3 requests, bs=4: the batch never fills, nothing can be judged — the
     # engine carries it to the next window where admission re-judges it
-    mask = _admit_mask(np.array([0.0, 0.1, 0.2]), np.full(3, 1e-6), 4,
-                       10.0, 0.0)
+    mask = _admit_mask_multi(np.array([0.0, 0.1, 0.2]), np.zeros(3, np.int64),
+                             [4], [10.0], np.full(3, 1e-6), 0.0)
     assert mask.all()
 
 
 def test_admit_mask_empty():
-    assert _admit_mask(np.empty(0), np.empty(0), 4, 0.01, 0.0).size == 0
+    assert _admit_mask_multi(np.empty(0), np.empty(0, np.int64), [4], [0.01],
+                             np.empty(0), 0.0).size == 0
     assert AdmissionPolicy("shed").admit(np.empty(0), 0.1, 4, 0.01,
                                          0.0).size == 0
 

@@ -29,8 +29,8 @@ W_IN = INFER_WORKLOADS["mobilenet"]
 
 
 def _fused_backend(backend):
-    """Skip guard for the fused-window cases: the fused program is jax-tier
-    (``pallas`` resolves to the same program; the engine tier is unused)."""
+    """Skip guard for the fused-window cases: the fused program is
+    jax-tier."""
     if not jax_available():
         pytest.skip("jax unavailable")
     return backend
@@ -390,48 +390,128 @@ def test_priority_batch_solver_matches_scalar(backend):
 # (f) the fused window: solve + admit + simulate as ONE launch per window
 # ---------------------------------------------------------------------------
 
+_LIGHT = [60.0, 110.0, 25.0, 80.0]
+# congested: an EWMA estimate lags each jump, so plans under-provision and
+# admission rejects, and the window after the 2400 req/s burst leaves
+# devices with no feasible plan (unserved)
+_HEAVY = [300.0, 2400.0, 500.0, 3000.0]
+_EWMA = dict(rate_estimator="ewma")
+# the fleet-k512-shed cell's controller (chipbench/traffic/fleet-30rps-shed)
+_CELL = dict(rate_estimator="ewma", rate_margin=1.5, feedback=True,
+             carry_backlog=True, mode_switch_s=0.25, burst_quantile=0.95,
+             admission="shed", tighten=0.5, relax=0.5, target_violation=0.0,
+             tail_quantile=0.95, min_budget_scale=0.2)
+
+
+def _shed(fus, _rung4):
+    return any(w.shed_requests for w in fus)
+
+
+def _redeferred(fus, _rung4):
+    # an unserved device drew re-offers (dispatch counts include them)
+    return any(d.solution is None and n > d.offered_requests
+               for w in fus for d, n in zip(w.devices, w.dispatch_counts))
+
+
 _FUSED_MATRIX = {
-    # name -> (FleetSpec kwargs, ControllerConfig kwargs): every fleet
-    # feature the fused program claims to cover, including combinations
-    "heterogeneous": (dict(time_spread=0.25, power_spread=0.15), dict()),
+    # name -> (FleetSpec kwargs, ControllerConfig kwargs, serve kwargs,
+    # what the run must show to have taken its branch, or None): every
+    # fleet feature the fused program claims to cover, and each branch of
+    # the shared prepare / settle stages, including combinations
+    "heterogeneous": (dict(time_spread=0.25, power_spread=0.15), dict(),
+                      dict(rates=[2.0, 0.0, 1.0]), None),   # idle devices
     "carried-backlog": (dict(), dict(rate_estimator="ewma",
                                      carry_backlog=True,
-                                     mode_switch_s=0.25)),
+                                     mode_switch_s=0.25), {}, None),
     "shed": (dict(), dict(admission="shed", carry_backlog=True,
-                          mode_switch_s=0.25)),
+                          mode_switch_s=0.25), {}, None),
     "defer": (dict(dispatch="least-backlog"),
               dict(admission="defer", defer_cap=25, carry_backlog=True,
                    rate_estimator="ewma", rate_margin=1.5, feedback=True,
-                   mode_switch_s=0.25)),
+                   mode_switch_s=0.25), {}, None),
     "water-filled": (dict(migrate_backlog=True, fleet_power_budget=80.0),
-                     dict(carry_backlog=True, feedback=True)),
+                     dict(carry_backlog=True, feedback=True), {}, None),
+    "fleet-cell": (
+        dict(time_spread=0.1, power_spread=0.05, dispatch="least-backlog"),
+        _CELL, dict(rates=_HEAVY),
+        lambda fus, _: _shed(fus, _) and any(
+            d.mode_switch_s and d.carried_requests
+            for w in fus for d in w.devices)),
+    "defer-idle": (dict(), dict(admission="defer", carry_backlog=True,
+                                **_EWMA),
+                   dict(rates=[300.0, 3000.0, 0.0, 300.0]), _redeferred),
+    "defer-cap-drops": (dict(), dict(admission="defer", defer_cap=2,
+                                     carry_backlog=True, **_EWMA),
+                        dict(rates=_HEAVY), _shed),
+    "least-backlog-open": (
+        dict(dispatch="least-backlog"), dict(carry_backlog=True, **_EWMA),
+        dict(rates=_HEAVY),
+        lambda fus, _: any(d.carried_requests
+                           for w in fus for d in w.devices)),
+    "migrate-shed": (
+        dict(migrate_backlog=True, time_spread=0.25),
+        dict(admission="shed", carry_backlog=True, **_EWMA),
+        dict(rates=_HEAVY),
+        lambda fus, _: _shed(fus, _) and any(w.migrated_requests
+                                             for w in fus)),
+    "power-cap-defer": (
+        dict(fleet_power_budget=150.0),
+        dict(admission="defer", carry_backlog=True, **_EWMA),
+        dict(rates=_HEAVY),
+        lambda fus, _: any(w.deferred_requests for w in fus)
+        and all(w.power_budgets is not None for w in fus)),
+    "burst-no-margin": (dict(), dict(burst_quantile=0.95, **_EWMA),
+                        dict(rates=_HEAVY), None),
+    "feedback-rung4": (dict(), dict(feedback=True, tighten=1.0,
+                                    min_budget_scale=0.05, **_EWMA),
+                       dict(rates=[300.0, 3000.0, 2400.0, 3000.0]),
+                       lambda _, rung4: rung4),
+    "uniform-arrivals": (dict(), dict(admission="shed", carry_backlog=True,
+                                      **_EWMA),
+                         dict(rates=_HEAVY, arrivals="uniform"), _shed),
+    "shed-headroom": (dict(), dict(admission="shed", admission_headroom=0.7,
+                                   **_EWMA),
+                      dict(rates=_HEAVY), _shed),
+    "defer-no-carry": (dict(), dict(admission="defer", **_EWMA),
+                       dict(rates=_HEAVY),
+                       lambda fus, _: any(w.deferred_requests for w in fus)),
+    "switch-no-carry": (dict(), dict(mode_switch_s=0.25, **_EWMA),
+                        dict(rates=_HEAVY),
+                        lambda fus, _: any(d.mode_switch_s for w in fus
+                                           for d in w.devices)),
 }
-
-# idle devices: rates so low whole windows dispatch nothing to some lanes
-_FUSED_RATES = {"idle": [2.0, 0.0, 1.0],
-                "default": [60.0, 110.0, 25.0, 80.0]}
 
 
 @pytest.mark.parametrize("case", sorted(_FUSED_MATRIX))
-@pytest.mark.parametrize("backend", ["jax", "pallas"])
-def test_fused_fleet_matches_unfused(case, backend):
+@pytest.mark.parametrize("backend", ["jax"])
+def test_fused_fleet_matches_unfused(case, backend, monkeypatch):
     _fused_backend(backend)
-    if backend == "pallas":
-        from repro.core.backend import pallas_available
-        if not pallas_available():
-            pytest.skip("pallas unavailable")
-    spec_kw, cfg_kw = _FUSED_MATRIX[case]
+    spec_kw, cfg_kw, serve_kw, takes = _FUSED_MATRIX[case]
     spec = F.FleetSpec(6, seed=3, **spec_kw)
     cfg = ControllerConfig(**cfg_kw)
-    rates = _FUSED_RATES["idle" if case == "heterogeneous" else "default"]
-    kw = dict(window_duration=3.0, arrivals="poisson", seed=17,
-              controller=cfg)
+    rates = serve_kw.get("rates", _LIGHT)
+    kw = dict(window_duration=3.0, arrivals=serve_kw.get("arrivals",
+                                                         "poisson"),
+              seed=17, controller=cfg)
+    # rung 4 fired for a device iff its plan misses the tightened budget:
+    # rungs 1-3 only accept plans within it
+    rung4 = []
+    ladder = F._solve_ladder
+
+    def spy(grid, ts, ps, pbud, bud, *rest):
+        sols = ladder(grid, ts, ps, pbud, bud, *rest)
+        rung4.extend(s is not None and s.time > b for s, b in zip(sols, bud))
+        return sols
+
+    monkeypatch.setattr(F, "_solve_ladder", spy)
     fus = F.serve_fleet(W_IN, 30.0, 0.15, rates, spec, backend=backend,
                         fused=True, **kw)
     unf = F.serve_fleet(W_IN, 30.0, 0.15, rates, spec, backend=backend,
                         **kw)
     seq = F.serve_fleet_sequential(W_IN, 30.0, 0.15, rates, spec,
                                    backend="numpy", **kw)
+    if takes is not None:
+        assert takes(fus, any(rung4))
     # same jax tier: only the associative scan's padded tree shape differs
     _assert_fleet_equal(fus, unf, exact=False)
     # and the exactness ladder back to the bitwise NumPy reference

@@ -103,142 +103,3 @@ def test_tiled_matmul_block_invariance():
     o1 = tiled_matmul(a, b, block_m=64, block_n=64, block_k=64, interpret=True)
     o2 = tiled_matmul(a, b, block_m=128, block_n=128, block_k=256, interpret=True)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), rtol=1e-5, atol=1e-4)
-
-
-# ---------------------------------------------------------------------------
-# fulcrum engine kernels: max-plus scan + lane sort. These run in float64
-# under enable_x64 (the engine's working precision) and are checked against
-# BOTH the lax.associative_scan oracle (ref.py) and an independent scalar
-# replay of the managed recurrence — tolerances per docs/exactness.md.
-# ---------------------------------------------------------------------------
-
-from repro.kernels.fulcrum.lane_sort import lane_sort
-from repro.kernels.fulcrum.maxplus_scan import maxplus_scan
-from repro.kernels.fulcrum.ref import (lane_sort_ref, lane_violations_ref,
-                                       maxplus_scan_ref)
-
-_enable_x64 = jax.enable_x64
-ENG_TOL = dict(rtol=1e-9, atol=1e-8)
-
-
-def _maxplus_case(rng, lanes, kmax):
-    """Ragged lanes padded the engine's way (+inf ready / 0 exec), with
-    random nonzero clocks (backlog carryover) and +inf t_tr / tau_cap
-    (no-training / uncapped lanes)."""
-    sizes = rng.integers(0, kmax + 1, lanes)
-    K = max(int(sizes.max(initial=0)), 1)
-    ready = np.full((lanes, K), np.inf)
-    exec_t = np.zeros((lanes, K))
-    for i, nsz in enumerate(sizes):
-        ready[i, :nsz] = np.sort(rng.uniform(0.0, 5.0, nsz))
-        exec_t[i, :nsz] = rng.uniform(0.01, 0.5, nsz)
-    t_tr = np.where(rng.random(lanes) < 0.3, np.inf,
-                    rng.uniform(0.05, 0.5, lanes))
-    cap = np.where(rng.random(lanes) < 0.5, np.inf,
-                   rng.integers(0, 5, lanes).astype(np.float64))
-    clock = np.where(rng.random(lanes) < 0.5, 0.0,
-                     rng.uniform(0.0, 2.0, lanes))
-    return ready, exec_t, t_tr, cap, clock, sizes
-
-
-def _maxplus_scalar(ready, exec_t, t_tr, cap, clock):
-    """Independent oracle: the managed recurrence replayed event-by-event in
-    Python (completion c_k = max(c_{k-1}, ready_k) + e_k, fills clipped to
-    the cap), skipping padded (+inf ready) events for the fill count."""
-    lanes, K = ready.shape
-    c = np.empty((lanes, K))
-    fills = np.zeros(lanes)
-    for i in range(lanes):
-        t = clock[i]
-        for k in range(K):
-            if np.isfinite(ready[i, k]):
-                gap = ready[i, k] - t
-                fills[i] += min(max(np.floor(gap / t_tr[i]), 0.0), cap[i])
-            t = max(t, ready[i, k]) + exec_t[i, k]
-            c[i, k] = t
-    return c, fills
-
-
-@pytest.mark.parametrize("seed,lanes,kmax", [(0, 1, 16), (1, 7, 33),
-                                             (2, 64, 5), (3, 17, 120)])
-def test_maxplus_scan_matches_ref_and_scalar(seed, lanes, kmax):
-    rng = np.random.default_rng(seed)
-    ready, exec_t, t_tr, cap, clock, sizes = _maxplus_case(rng, lanes, kmax)
-    with _enable_x64():
-        c, fills = maxplus_scan(jnp.asarray(ready), jnp.asarray(exec_t),
-                                jnp.asarray(t_tr), jnp.asarray(cap),
-                                jnp.asarray(clock), interpret=True)
-        cr, fr = maxplus_scan_ref(jnp.asarray(ready), jnp.asarray(exec_t),
-                                  jnp.asarray(t_tr), jnp.asarray(cap),
-                                  jnp.asarray(clock))
-    c, fills = np.asarray(c), np.asarray(fills)
-    cr, fr = np.asarray(cr), np.asarray(fr)
-    cs, fs = _maxplus_scalar(ready, exec_t, t_tr, cap, clock)
-    for i, nsz in enumerate(sizes):
-        np.testing.assert_allclose(c[i, :nsz], cr[i, :nsz], **ENG_TOL)
-        np.testing.assert_allclose(c[i, :nsz], cs[i, :nsz], **ENG_TOL)
-    np.testing.assert_allclose(fills, fr, **ENG_TOL)
-    assert np.all(np.abs(fills - fs) <= 2)     # floor-boundary slack
-
-
-@pytest.mark.parametrize("bl", [1, 3, 8, 64])
-def test_maxplus_scan_block_invariance(bl):
-    """Per-lane arithmetic is independent of the lane blocking — results
-    must be bitwise identical whatever block_lanes is."""
-    rng = np.random.default_rng(42)
-    ready, exec_t, t_tr, cap, clock, _ = _maxplus_case(rng, 13, 40)
-    with _enable_x64():
-        args = (jnp.asarray(ready), jnp.asarray(exec_t), jnp.asarray(t_tr),
-                jnp.asarray(cap), jnp.asarray(clock))
-        c_a, f_a = maxplus_scan(*args, block_lanes=bl, interpret=True)
-        c_b, f_b = maxplus_scan(*args, block_lanes=256, interpret=True)
-        np.testing.assert_array_equal(np.asarray(c_a), np.asarray(c_b))
-        np.testing.assert_array_equal(np.asarray(f_a), np.asarray(f_b))
-
-
-def test_maxplus_scan_empty_edges():
-    with _enable_x64():
-        c, f = maxplus_scan(jnp.zeros((0, 4)), jnp.zeros((0, 4)),
-                            jnp.zeros((0,)), jnp.zeros((0,)),
-                            jnp.zeros((0,)), interpret=True)
-    assert np.asarray(c).shape == (0, 4) and np.asarray(f).shape == (0,)
-
-
-def _sort_case(rng, lanes, reqs):
-    mat = np.full((lanes, reqs), np.inf)
-    for i in range(lanes):
-        nsz = int(rng.integers(0, reqs + 1))
-        mat[i, :nsz] = rng.uniform(1e-4, 10.0, nsz)
-    return mat
-
-
-@pytest.mark.parametrize("seed,lanes,reqs", [(0, 1, 1), (1, 9, 17),
-                                             (2, 33, 64), (3, 8, 100)])
-def test_lane_sort_exact_vs_numpy(seed, lanes, reqs):
-    """Sorting permutes values — the sorted matrix must be *equal* to
-    NumPy's sort, not merely close (and to the jnp oracle)."""
-    rng = np.random.default_rng(50 + seed)
-    mat = _sort_case(rng, lanes, reqs)
-    budgets = rng.uniform(0.1, 5.0, lanes)
-    with _enable_x64():
-        srt, viol = lane_sort(jnp.asarray(mat), jnp.asarray(budgets),
-                              interpret=True)
-        ref = lane_sort_ref(jnp.asarray(mat))
-        vref = lane_violations_ref(jnp.asarray(mat), jnp.asarray(budgets))
-    np.testing.assert_array_equal(np.asarray(srt), np.sort(mat, axis=1))
-    np.testing.assert_array_equal(np.asarray(srt), np.asarray(ref))
-    want = [(np.isfinite(mat[i]) & (mat[i] > budgets[i])).sum()
-            for i in range(lanes)]
-    np.testing.assert_array_equal(np.asarray(viol), np.asarray(want))
-    np.testing.assert_array_equal(np.asarray(viol), np.asarray(vref))
-
-
-@pytest.mark.parametrize("bl", [1, 5, 256])
-def test_lane_sort_block_invariance_and_sorted_only(bl):
-    rng = np.random.default_rng(77)
-    mat = _sort_case(rng, 11, 23)
-    with _enable_x64():
-        a = lane_sort(jnp.asarray(mat), block_lanes=bl, interpret=True)
-        b = lane_sort(jnp.asarray(mat), block_lanes=8, interpret=True)
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    np.testing.assert_array_equal(np.asarray(a), np.sort(mat, axis=1))

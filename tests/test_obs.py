@@ -67,9 +67,9 @@ def test_trace_counters_count_compilations_not_calls():
     n0, d0 = S.engine_trace_count(), dispatch_count("engine")
     args = (ready, execs, np.full(1, np.inf), np.full(1, np.inf),
             np.zeros(1))
-    S._run_engine("jax", *args)
+    S._run_engine(*args)
     n1 = S.engine_trace_count()
-    S._run_engine("jax", *args)
+    S._run_engine(*args)
     assert S.engine_trace_count() == n1 >= n0
     assert dispatch_count("engine") - d0 == 2
 

@@ -419,90 +419,53 @@ def test_batched_report_builder_matches_per_report_statistics():
             assert rep.violation_rate(budget) == want
 
 
-# ---------------------------------------------------------------------------
-# pallas backend tier: the hand-written kernels behind the same entry points
-# ---------------------------------------------------------------------------
-
-needs_pallas = pytest.mark.skipif(not B.pallas_available(),
-                                  reason="pallas unavailable")
-
-
-@needs_pallas
-@pytest.mark.parametrize("seed", range(2))
-def test_pallas_engine_matches_numpy_randomized(seed):
-    rng = np.random.default_rng(300 + seed)
-    w_tr = TRAIN_WS[seed % len(TRAIN_WS)] if seed % 2 == 0 else None
-    w_in = INFER_WS[seed % len(INFER_WS)]
-    pms, bss, traces, caps = [], [], [], []
-    for _ in range(6):
-        _, _, pm, bs, trace, cap = _random_config(rng)
-        pms.append(pm), bss.append(bs), traces.append(trace), caps.append(cap)
-    ref = S.simulate_batch(DEV, w_tr, w_in, pms, bss, traces,
-                           tau_caps=caps, backend="numpy")
-    got = S.simulate_batch(DEV, w_tr, w_in, pms, bss, traces,
-                           tau_caps=caps, backend="pallas")
-    for a, b in zip(ref, got):
-        _assert_engine_close(a, b)
-        # the pallas report builder sorts with the bitonic kernel: sorting
-        # permutes values, so the cache must EQUAL sorting its own latencies
-        assert b._sorted is not None
-        np.testing.assert_array_equal(
-            b._sorted, np.sort(np.asarray(b.latencies, np.float64)))
-
-
-@needs_pallas
-def test_pallas_single_simulate_matches_numpy():
-    w_tr = TRAIN_WORKLOADS["mobilenet"]
-    w_in = INFER_WORKLOADS["mobilenet"]
-    trace = S.ArrivalTrace.poisson(60.0, 20.0, seed=7)
-    ref = S.simulate(DEV, w_tr, w_in, SPACE.maxn(), 16, trace, "managed")
-    got = S.simulate(DEV, w_tr, w_in, SPACE.maxn(), 16, trace, "managed",
-                     backend="pallas")
-    _assert_engine_close(ref, got)
-
-
-@needs_pallas
-def test_pallas_multi_tenant_matches_numpy():
-    ws = [INFER_WORKLOADS["mobilenet"], INFER_WORKLOADS["lstm"]]
-    traces = [S.ArrivalTrace.poisson(30.0, 15.0, seed=1),
-              S.ArrivalTrace.uniform(50.0, 15.0)]
-    ref = S.simulate_multi_tenant(DEV, TRAIN_WORKLOADS["resnet18"], ws,
-                                  SPACE.maxn(), [4, 16], traces)
-    got = S.simulate_multi_tenant(DEV, TRAIN_WORKLOADS["resnet18"], ws,
-                                  SPACE.maxn(), [4, 16], traces,
-                                  backend="pallas")
-    assert abs(ref.train_minibatches - got.train_minibatches) <= 2
-    for ra, rb in zip(ref.streams, got.streams):
-        np.testing.assert_allclose(np.asarray(rb.latencies, np.float64),
-                                   np.asarray(ra.latencies, np.float64),
-                                   **TOL)
-
-
-def test_env_pallas_degrades_down_tiers(monkeypatch):
-    """An environment-level 'pallas' request degrades pallas -> jax -> numpy
-    as capabilities vanish; an *explicit* backend='pallas' argument raises."""
-    monkeypatch.setenv(B.ENGINE_BACKEND_ENV, "pallas")
-    if B.pallas_available():
-        assert B.resolve_backend(None) == "pallas"
-    monkeypatch.setattr(B, "_PALLAS_OK", False)
-    if B.jax_available():
-        assert B.resolve_backend(None) == "jax"
-    monkeypatch.setattr(B, "_JAX_OK", False)
-    assert B.resolve_backend(None) == "numpy"
-    with pytest.raises(RuntimeError, match="pallas"):
-        B.resolve_backend("pallas")
-
-
-def test_grid_solvers_reject_pallas_backend():
-    """The 'pallas' tier is engine-only: the grid solvers must refuse it
-    loudly instead of silently falling back to the NumPy branch."""
+def _entry_points(backend):
+    """Every public call that resolves an engine or solver backend, each
+    asked for ``backend`` (``None`` defers to the environment)."""
+    from repro.core import fleet as F
     from repro.core import grid_eval as G
-    with pytest.raises(ValueError, match="unknown backend"):
-        G.solve_train_batch([], {}, backend="pallas")
-    with pytest.raises(ValueError, match="unknown backend"):
-        G.solve_infer_batch([], {}, backend="pallas")
-    with pytest.raises(ValueError, match="unknown backend"):
-        G.solve_concurrent_batch([], {}, {}, backend="pallas")
+    w_in = INFER_WORKLOADS["mobilenet"]
+    trace = S.ArrivalTrace.uniform(20.0, 1.0)
+    pm = SPACE.maxn()
+    yield lambda: B.resolve_backend(backend)
+    yield lambda: S.simulate(DEV, None, w_in, pm, 4, trace, backend=backend)
+    yield lambda: S.simulate_batch(DEV, None, w_in, [pm], [4], [trace],
+                                   backend=backend)
+    yield lambda: S.simulate_multi_tenant(DEV, None, [w_in], pm, [4],
+                                          [trace], backend=backend)
+    yield lambda: S.simulate_multi_tenant_batch(
+        DEV, None, [[w_in]], [pm], [[4]], [[trace]], backend=backend)
+    yield lambda: F.serve_fleet(w_in, 30.0, 0.1, [20.0], F.FleetSpec(2),
+                                window_duration=1.0, backend=backend)
+    if backend is not None:             # the solvers take no env request
+        yield lambda: G.solve_train_batch([], {}, backend=backend)
+        yield lambda: G.solve_infer_batch([], {}, backend=backend)
+        yield lambda: G.solve_infer_fleet_batch([], [], {}, [], [],
+                                                backend=backend)
+        yield lambda: G.solve_concurrent_batch([], {}, {}, backend=backend)
+        yield lambda: G.solve_multi_tenant_batch([], None, [],
+                                                 backend=backend)
+
+
+def test_env_unknown_tier_raises_at_every_entry_point(monkeypatch):
+    """An environment request for a tier that does not exist ('pallas' was
+    one) is refused like any unknown name: it never degrades to a tier
+    that does."""
+    for name in ("pallas", "torch"):
+        monkeypatch.setenv(B.ENGINE_BACKEND_ENV, name)
+        for call in _entry_points(None):
+            with pytest.raises(ValueError, match="unknown backend"):
+                call()
+
+
+def test_explicit_unknown_tier_raises_at_every_entry_point():
+    """An explicit request for an unknown tier raises at every entry point,
+    the grid solvers included, instead of silently running NumPy."""
+    assert B.BACKEND_TIERS == ("jax", "numpy")
+    for name in ("pallas", "torch"):
+        for call in _entry_points(name):
+            with pytest.raises(ValueError, match="unknown backend"):
+                call()
 
 
 # ---------------------------------------------------------------------------
@@ -528,50 +491,129 @@ def test_engine_trace_count_stable_within_shape_bucket():
     assert S.engine_trace_count() == n0
 
 
-@needs_pallas
-def test_pallas_trace_count_stable_within_shape_bucket():
-    w_in = INFER_WORKLOADS["lstm"]
-    trace = S.ArrivalTrace.poisson(25.0, 4.0, seed=5)
-
-    def batch(n):
-        S.simulate_batch(DEV, None, w_in, [SPACE.maxn()] * n, [4] * n,
-                         [trace] * n, backend="pallas")
-
-    batch(4)
-    n0 = S.engine_trace_count()
-    batch(4)
-    batch(7)
-    assert S.engine_trace_count() == n0
-
-
 # ---------------------------------------------------------------------------
 # chunked report builder: chunking must be invisible (bitwise)
 # ---------------------------------------------------------------------------
 
-def test_presort_chunking_bitwise_identical(monkeypatch):
-    """Force tiny sort chunks: the per-report sorted caches must be bitwise
-    identical to one unchunked NumPy sort per report."""
-    rng = np.random.default_rng(9)
-    reports = []
-    for _ in range(13):
-        xs = rng.uniform(0.0, 3.0, int(rng.integers(0, 40))).tolist()
-        reports.append(S.ExecutionReport("managed", xs, 0, 1.0, 0.0))
+def _sort_case(rng, lanes, reqs, ragged):
+    """Per-lane latency vectors of 0..reqs requests; ``ragged`` gives one
+    full lane beside near-empty ones, so padding would cost far more than
+    it batches (the report builder's per-report bail-out)."""
+    if ragged:
+        sizes = np.concatenate([[reqs], rng.integers(0, 3, lanes - 1)])
+    else:
+        sizes = rng.integers(0, reqs + 1, lanes)
+    return [S.ExecutionReport("managed", rng.uniform(1e-4, 10.0, n).tolist(),
+                              0, 1.0, 0.0) for n in sizes]
+
+
+@pytest.mark.parametrize("seed,lanes,reqs,chunk,ragged", [
+    (0, 1, 1, 256, False), (1, 9, 17, 5, False), (2, 33, 64, 1, False),
+    (3, 8, 100, 256, True), (4, 11, 23, 1, False), (4, 11, 23, 5, False),
+    (5, 33, 64, 5, True)])
+def test_presort_chunking_bitwise_identical(monkeypatch, seed, lanes, reqs,
+                                            chunk, ragged):
+    """Sort chunks of ``chunk`` lanes or more: the per-report sorted caches
+    must be bitwise identical to one unchunked NumPy sort per report,
+    whether a chunk pads into one matrix or bails out to per-report sorts."""
+    reports = _sort_case(np.random.default_rng(50 + seed), lanes, reqs,
+                         ragged)
     want = [np.sort(np.asarray(r.latencies, np.float64)) for r in reports]
-    monkeypatch.setattr(S, "_SORT_CHUNK_ELEMS", 64)
+    monkeypatch.setattr(S, "_SORT_CHUNK_ELEMS", chunk * reqs)
+    bailed = []
+    sort_chunk = S._sort_lane_chunk
+
+    def spy(lats, reps):
+        width = max(a.size for a in lats)
+        bailed.append(len(lats) * width > 4 * sum(a.size for a in lats))
+        sort_chunk(lats, reps)
+
+    monkeypatch.setattr(S, "_sort_lane_chunk", spy)
     S._presort_reports(reports)
     for rep, w in zip(reports, want):
         assert rep._sorted is not None
         np.testing.assert_array_equal(rep._sorted, w)
+    if ragged:
+        assert any(bailed)
 
 
-@needs_pallas
-def test_presort_pallas_backend_equals_numpy_sort(monkeypatch):
-    rng = np.random.default_rng(11)
-    reports = [S.ExecutionReport(
-        "managed", rng.uniform(0.0, 3.0, int(rng.integers(1, 30))).tolist(),
-        0, 1.0, 0.0) for _ in range(9)]
-    want = [np.sort(np.asarray(r.latencies, np.float64)) for r in reports]
-    monkeypatch.setattr(S, "_SORT_CHUNK_ELEMS", 128)   # exercise chunk loop
-    S._presort_reports(reports, backend="pallas")
-    for rep, w in zip(reports, want):
-        np.testing.assert_array_equal(rep._sorted, w)
+# ---------------------------------------------------------------------------
+# the jax scan runner on raw padded lanes: ragged lanes padded the engine's
+# way, +inf t_tr / tau_cap, caps of 0-4 and carried clocks, against an
+# independent scalar replay (tolerances per docs/exactness.md)
+# ---------------------------------------------------------------------------
+
+def _maxplus_case(rng, lanes, kmax):
+    """Ragged lanes padded the engine's way (+inf ready / 0 exec), with
+    random nonzero clocks (backlog carryover) and +inf t_tr / tau_cap
+    (no-training / uncapped lanes)."""
+    sizes = rng.integers(0, kmax + 1, lanes)
+    K = max(int(sizes.max(initial=0)), 1)
+    ready = np.full((lanes, K), np.inf)
+    exec_t = np.zeros((lanes, K))
+    for i, nsz in enumerate(sizes):
+        ready[i, :nsz] = np.sort(rng.uniform(0.0, 5.0, nsz))
+        exec_t[i, :nsz] = rng.uniform(0.01, 0.5, nsz)
+    t_tr = np.where(rng.random(lanes) < 0.3, np.inf,
+                    rng.uniform(0.05, 0.5, lanes))
+    cap = np.where(rng.random(lanes) < 0.5, np.inf,
+                   rng.integers(0, 5, lanes).astype(np.float64))
+    clock = np.where(rng.random(lanes) < 0.5, 0.0,
+                     rng.uniform(0.0, 2.0, lanes))
+    return ready, exec_t, t_tr, cap, clock, sizes
+
+
+def _maxplus_scalar(ready, exec_t, t_tr, cap, clock):
+    """Independent oracle: the managed recurrence replayed event-by-event in
+    Python (completion c_k = max(c_{k-1}, ready_k) + e_k, fills clipped to
+    the cap), skipping padded (+inf ready) events for the fill count."""
+    lanes, K = ready.shape
+    c = np.empty((lanes, K))
+    fills = np.zeros(lanes)
+    for i in range(lanes):
+        t = clock[i]
+        for k in range(K):
+            if np.isfinite(ready[i, k]):
+                gap = ready[i, k] - t
+                fills[i] += min(max(np.floor(gap / t_tr[i]), 0.0), cap[i])
+            t = max(t, ready[i, k]) + exec_t[i, k]
+            c[i, k] = t
+    return c, fills
+
+
+@needs_jax
+@pytest.mark.parametrize("seed,lanes,kmax", [(0, 1, 16), (1, 7, 33),
+                                             (2, 64, 5), (3, 17, 120)])
+def test_jax_scan_runner_matches_scalar_replay(seed, lanes, kmax):
+    rng = np.random.default_rng(seed)
+    ready, exec_t, t_tr, cap, clock, sizes = _maxplus_case(rng, lanes, kmax)
+    c, fills = S._jax_engine()(ready, exec_t, t_tr, cap, clock)
+    cs, fs = _maxplus_scalar(ready, exec_t, t_tr, cap, clock)
+    for i, nsz in enumerate(sizes):
+        np.testing.assert_allclose(c[i, :nsz], cs[i, :nsz], **TOL)
+    assert np.all(np.abs(fills - fs) <= 2)     # floor-boundary slack
+
+
+@needs_jax
+def test_jax_scan_runner_empty_edges():
+    c, f = S._jax_engine()(np.zeros((0, 4)), np.zeros((0, 4)), np.zeros(0),
+                           np.zeros(0), np.zeros(0))
+    assert c.shape == (0, 4) and f.shape == (0,)
+
+
+@needs_jax
+@pytest.mark.parametrize("chunk", [1, 3, 8, 64])
+def test_run_engine_lane_chunk_invariance(monkeypatch, chunk):
+    """Per-lane arithmetic is independent of how lanes are cut into
+    compiled calls: completions and fills must be bitwise identical to one
+    chunk, whatever ``_LANE_CHUNK`` is."""
+    rng = np.random.default_rng(42)
+    ready, exec_t, t_tr, cap, clock, sizes = _maxplus_case(rng, 13, 40)
+    readies = [ready[i, :n] for i, n in enumerate(sizes)]
+    execs = [exec_t[i, :n] for i, n in enumerate(sizes)]
+    one_c, one_f = S._run_engine(readies, execs, t_tr, cap, clock)
+    monkeypatch.setattr(S, "_LANE_CHUNK", chunk)
+    c, f = S._run_engine(readies, execs, t_tr, cap, clock)
+    for a, b in zip(c, one_c):
+        np.testing.assert_array_equal(a, b)
+    np.testing.assert_array_equal(f, one_f)

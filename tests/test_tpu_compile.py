@@ -3,8 +3,7 @@
 The device path is compiled at its real sizes for a described v5e
 (``topologies.get_topology_desc``), so whatever the chip's compiler refuses
 fails here instead of on the chip. Nothing runs: these tests say nothing
-about results or times. The two Pallas engine kernels are refused today;
-they are strict xfails that name the refusal, so a fix flips them.
+about results or times.
 
 The topology is described inside a module-scoped fixture only: describing
 it loads the TPU library, which one process at a time may hold.
@@ -27,7 +26,6 @@ ENGINE_LANES, ENGINE_EVENTS = 8192, 1024    # simulate._LANE_CHUNK x 1024
 FLEET_K, FLEET_T = 512, 512                 # K=512 fleet, pow2 event bucket
 GRID_N = 441 * len(INFER_BATCH_SIZES)       # every power mode x every bs
 SOLVER_ROWS = 2048                          # one pow2 chunk of problems
-SORT_REQS = 256                             # requests per lane to sort
 
 
 @pytest.fixture(scope="module")
@@ -102,47 +100,3 @@ def test_concurrent_solver_compiles(one_chip):
     args = (col(jnp.float64), col(jnp.float64), col(jnp.float64),
             col(jnp.float64), col(jnp.bool_), row(), row(), row())
     _fits_one_chip(_compile(kernel, *args))
-
-
-def _refused_as(compile_fn, refusal: str):
-    """Compile; a refusal that does not name ``refusal`` is a failure of
-    its own, not the expected one."""
-    try:
-        compile_fn()
-    except Exception as e:
-        if refusal not in str(e):
-            pytest.fail(f"refused for another reason: {e}")
-        raise
-
-
-MAXPLUS_REFUSAL = "'tpu.weird' op Input type must be F32"
-
-
-@pytest.mark.xfail(strict=True, raises=Exception,
-                   reason=f"v5e refuses the f64 kernel: {MAXPLUS_REFUSAL}")
-def test_maxplus_scan_kernel_compiles(one_chip):
-    from repro.kernels.fulcrum.maxplus_scan import maxplus_scan
-    f64 = functools.partial(_sds, one_chip, dtype=jnp.float64)
-    fn = jax.jit(functools.partial(maxplus_scan, interpret=False))
-    lanes = (ENGINE_LANES,)
-    _refused_as(lambda: _compile(fn, f64((ENGINE_LANES, ENGINE_EVENTS)),
-                                 f64((ENGINE_LANES, ENGINE_EVENTS)),
-                                 f64(lanes), f64(lanes), f64(lanes)),
-                MAXPLUS_REFUSAL)
-
-
-# the report builder sorts float64 under x64; float32 with x64 off is
-# refused too, for its layout
-@pytest.mark.parametrize("dtype,refusal", [
-    (jnp.float64, "64-bit types are not supported"),
-    (jnp.float32, "unsupported shape cast")], ids=["f64", "f32"])
-@pytest.mark.xfail(strict=True, raises=Exception,
-                   reason="v5e refuses the bitonic lane-sort kernel")
-def test_lane_sort_kernel_compiles(one_chip, dtype, refusal):
-    from repro.kernels.fulcrum.lane_sort import lane_sort
-    sds = functools.partial(_sds, one_chip, dtype=dtype)
-    fn = jax.jit(functools.partial(lane_sort, interpret=False))
-    _refused_as(lambda: _compile(fn, sds((ENGINE_LANES, SORT_REQS)),
-                                 sds((ENGINE_LANES,)),
-                                 x64=dtype == jnp.float64),
-                refusal)
