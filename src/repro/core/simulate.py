@@ -182,14 +182,30 @@ class ArrivalTrace:
                             ids[order], len(traces))
 
     def split(self, n_streams: Optional[int] = None) -> list["ArrivalTrace"]:
-        """Per-stream traces of a merged trace (provenance round-trip)."""
+        """Per-stream traces of a merged trace (provenance round-trip):
+        stream ``j`` gets ``times[stream_ids == j]``, in time order; ids
+        outside ``[0, n)`` are dropped. One grouping pass, not one mask per
+        stream: a stable sort of the ids (a radix sort when they fit 16
+        bits) keeps each stream's arrivals in order, and the per-stream
+        counts cut the gathered times into ``n`` pieces (views of one
+        array)."""
         if self.stream_ids is None:
             raise ValueError("trace has no stream provenance; use merge()")
         n = n_streams if n_streams is not None else self.n_streams
         if n is None:       # foreign ids without a recorded count: infer
             n = int(self.stream_ids.max() + 1) if len(self) else 0
-        return [ArrivalTrace(self.times[self.stream_ids == j], self.duration,
-                             self.kind) for j in range(int(n))]
+        n = int(n)
+        if n <= 0:
+            return []
+        ids, times = self.stream_ids, self.times
+        keep = (ids >= 0) & (ids < n)
+        if not keep.all():
+            ids, times = ids[keep], times[keep]
+        key = ids.astype(np.uint16) if n <= 1 << 16 else ids
+        grouped = times[np.argsort(key, kind="stable")]
+        ends = np.cumsum(np.bincount(ids, minlength=n)).tolist()
+        return [ArrivalTrace(grouped[a:b], self.duration, self.kind)
+                for a, b in zip([0] + ends[:-1], ends)]
 
     @classmethod
     def uniform(cls, rate: float, duration: float) -> "ArrivalTrace":

@@ -211,6 +211,35 @@ def test_fleet_deferred_reenter_the_dispatcher():
         assert int(cur.dispatch_counts.sum()) == len(cur.trace)
 
 
+def test_fleet_k512_device_traces_equal_dispatch_masks(monkeypatch):
+    """At the benchmark's fleet size, the per-device traces a shed-admission
+    window runs are exactly the dispatch masks ``agg.times[sid == d]``:
+    the one-pass grouping pinned at K=512, not only at a handful of
+    devices (2 req/s per device keeps the three windows small)."""
+    K = 512
+    seen = []
+    split_window = F.split_window
+
+    def spy(agg, sid, n_devices):
+        merged, dtr = split_window(agg, sid, n_devices)
+        seen.append((agg.times.copy(), np.asarray(sid).copy(), dtr))
+        return merged, dtr
+
+    monkeypatch.setattr(F, "split_window", spy)
+    wins = F.serve_fleet(W_IN, 30.0, 0.1, [2.0 * K, 3.0 * K, 1.5 * K],
+                         F.FleetSpec(K, seed=3), window_duration=2.0,
+                         arrivals="poisson", seed=5, backend="numpy",
+                         controller=_cfg("shed"))
+    assert len(seen) == len(wins) == 3
+    for (times, sid, dtr), fw in zip(seen, wins):
+        assert len(dtr) == K and np.array_equal(fw.trace.stream_ids, sid)
+        for d in range(K):
+            assert dtr[d].times.dtype == np.float64
+            assert np.array_equal(dtr[d].times, times[sid == d])
+            assert dtr[d].duration == fw.trace.duration
+            assert fw.devices[d].offered_requests == len(dtr[d])
+
+
 # ---------------------------------------------------------------------------
 # (c) conservation: migration moves requests, never loses or mints them
 # ---------------------------------------------------------------------------

@@ -251,6 +251,55 @@ def test_merge_split_keeps_idle_tenants():
     assert back[2].times.tolist() == traces[2].times.tolist()
 
 
+_SPLIT_CASES = ("n=1", "n=3", "n=512", "wide-ids", "empty", "idle-streams",
+                "tied-times", "ids-outside")
+
+
+def _split_case(case):
+    """(times, ids, n_streams) for one grouping case of ``split``."""
+    rng = np.random.default_rng(_SPLIT_CASES.index(case))
+    sorted_times = lambda k: np.sort(rng.uniform(0.0, 5.0, k))
+    if case.startswith("n="):
+        n = int(case[2:])
+        return sorted_times(2000), rng.integers(0, n, 2000), n
+    if case == "wide-ids":      # n past 16 bits: ids that wrap mod 2**16
+        ids = rng.integers(0, 70_000, 300)    # must not collide with 3, 4
+        ids[:4] = [3, 3 + (1 << 16), 4, 4 + (1 << 16)]
+        return sorted_times(300), rng.permutation(ids), 70_000
+    if case == "empty":
+        return np.empty(0), np.empty(0, np.int64), 4
+    if case == "idle-streams":  # only streams 1 and 5 of 8 draw arrivals
+        return sorted_times(200), rng.choice([1, 5], 200), 8
+    if case == "tied-times":    # equal times across (and within) streams
+        return np.sort(np.round(rng.uniform(0, 1, 400), 1)), \
+            rng.integers(0, 3, 400), 3
+    if case == "ids-outside":   # ids < 0 or >= n are dropped
+        return sorted_times(500), rng.integers(-2, 9, 500), 6
+    raise ValueError(case)
+
+
+@pytest.mark.parametrize("case", _SPLIT_CASES)
+def test_split_equals_per_stream_masks(case):
+    """``split`` groups in one pass; it must give exactly the per-stream
+    mask definition ``times[stream_ids == j]``: the same float64 values in
+    the same order (pinned through arrival positions, which ties cannot
+    hide), the same duration and kind, empty idle streams, and nothing of
+    an id outside ``[0, n)``."""
+    times, ids, n = _split_case(case)
+    trace = S.ArrivalTrace(times, 5.0, "merged", ids, n)
+    pos = S.ArrivalTrace(np.arange(len(ids), dtype=np.float64), 5.0,
+                         "merged", ids, n)
+    back, back_pos = trace.split(), pos.split()
+    assert len(back) == len(back_pos) == n
+    for j, (tr, tp) in enumerate(zip(back, back_pos)):
+        want = trace.times[trace.stream_ids == j]
+        assert tr.times.dtype == np.float64
+        assert np.array_equal(tr.times, want)
+        assert np.array_equal(tp.times, np.flatnonzero(ids == j))
+        assert tr.duration == 5.0 and tr.kind == "merged"
+        assert tr.stream_ids is None and tr.n_streams is None
+
+
 def test_batch_rejects_mixed_stream_workloads():
     """A problem batch shares one observation set per stream, so mixing
     stream workloads across the batch must be an error, not a silent solve
